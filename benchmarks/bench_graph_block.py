@@ -37,52 +37,14 @@ CASES = [
 B, S = 2, 8
 
 
-def _init_weights(prog, rng):
-    import numpy as np
-
-    ws = {}
-    for t in prog.graph.parameters():
-        shp = tuple(t.shape)
-        ws[t.name] = np.ones(shp, np.float32) \
-            if "norm" in t.name.split("/")[-1] \
-            else (rng.standard_normal(shp) * 0.05).astype(np.float32)
-    return ws
-
-
 def _reference_step(cfg, ids, labels):
     """jit'd fwd+bwd of the plain-jax twin of ``build_block``."""
     import jax
-    import jax.numpy as jnp
 
-    from repro.models import layers
+    from repro.models.graph_block import reference_loss
 
-    eps = cfg.norm_eps
-
-    def loss(params):
-        x = params["embed"][ids]
-        for i in range(cfg.n_layers):
-            p = {k.split("/", 1)[1]: v for k, v in params.items()
-                 if k.startswith(f"l{i}/")}
-            ap = {k: p[k] for k in ("wq", "wk", "wv", "wo")}
-            for bn in ("bq", "bk", "bv"):
-                if bn in p:
-                    ap[bn] = p[bn]
-            h = layers.rms_norm({"w": p["attn_norm"]}, x, eps)
-            y, _ = layers.apply_attention(ap, h, cfg, positions=None,
-                                          causal=True, use_rope=False)
-            x = x + y
-            h = layers.rms_norm({"w": p["mlp_norm"]}, x, eps)
-            x = x + layers.apply_mlp(
-                {"gate": p["w_gate"], "up": p["w_up"],
-                 "down": p["w_down"]}, h, cfg.mlp)
-        x = layers.rms_norm({"w": params["final_norm"]}, x, eps)
-        lm = params["embed"].T if cfg.tie_embeddings \
-            else params["lm_head"]
-        probs = jax.nn.softmax(x @ lm, -1)
-        return jnp.take_along_axis(
-            probs, labels[..., None], -1)[..., 0].mean()
-
-    return jax.jit(jax.value_and_grad(loss))
+    return jax.jit(jax.value_and_grad(
+        reference_loss(cfg, cfg.n_layers, ids, labels)))
 
 
 def _time_calls(fn, warmup, iters):
@@ -120,7 +82,7 @@ def bench(smoke: bool = False) -> dict:
 
     from repro import api
     from repro.configs import get_config
-    from repro.models.graph_block import block_program
+    from repro.models.graph_block import block_program, init_block_weights
 
     warmup, iters = (0, 1) if smoke else (1, 3)
     cases = CASES[:1] if smoke else CASES
@@ -130,7 +92,7 @@ def bench(smoke: bool = False) -> dict:
         n_dev = par["dp"] * par["tp"] * par["pp"]
         prog = block_program(cfg, batch=B, seq=S, **par)
         rng = np.random.default_rng(0)
-        ws = _init_weights(prog, rng)
+        ws = init_block_weights(prog, rng)
         ids = rng.integers(0, cfg.vocab, (B, S)).astype(np.int32)
         labels = rng.integers(0, cfg.vocab, (B, S)).astype(np.int32)
         feeds = {"ids": ids, "labels": labels}

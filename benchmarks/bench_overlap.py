@@ -52,18 +52,6 @@ B, S = 2, 8
 MICRO_SHAPE = (256, 256)
 
 
-def _init_weights(prog, rng):
-    import numpy as np
-
-    ws = {}
-    for t in prog.graph.parameters():
-        shp = tuple(t.shape)
-        ws[t.name] = np.ones(shp, np.float32) \
-            if "norm" in t.name.split("/")[-1] \
-            else (rng.standard_normal(shp) * 0.05).astype(np.float32)
-    return ws
-
-
 def _time_calls(fn, warmup, iters):
     for _ in range(warmup):
         fn()
@@ -142,7 +130,7 @@ def bench(smoke: bool = False) -> dict:
 
     from repro import api
     from repro.configs import get_config
-    from repro.models.graph_block import block_program
+    from repro.models.graph_block import block_program, init_block_weights
 
     warmup, iters = (0, 1) if smoke else (1, 3)
     cases = [c for c in CASES if c[2] > 1] if smoke else CASES
@@ -157,7 +145,7 @@ def bench(smoke: bool = False) -> dict:
         cfg = get_config(arch).reduced()
         prog = block_program(cfg, batch=B, seq=S, **par)
         rng = np.random.default_rng(0)
-        ws = _init_weights(prog, rng)
+        ws = init_block_weights(prog, rng)
         feeds = {
             "ids": rng.integers(0, cfg.vocab, (B, S)).astype(np.int32),
             "labels": rng.integers(0, cfg.vocab,

@@ -96,6 +96,13 @@ class CompiledPlan:
                     for p in self.specialization.pipelines), default=1)
 
     @property
+    def train_fetches(self) -> list[str]:
+        """What one training step of a TRAIN plan fetches: the loss,
+        then each parameter's gradient in parameter order."""
+        return [self.loss_name] + [self.grad_map[t.name]
+                                   for t in self.graph.parameters()]
+
+    @property
     def virtual_stages_per_device(self) -> int:
         """Megatron's ``v``: how many model chunks this graph's dataflow
         places on each physical stage (1 unless the strategy routes the

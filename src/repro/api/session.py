@@ -310,7 +310,7 @@ class Session:
                 raise ValueError(
                     f"parameter {name!r} not loaded; call session.load")
         grad_fetch = [tplan.grad_map[p] for p in params]
-        fetch_list = [tplan.loss_name] + grad_fetch + list(fetches)
+        fetch_list = tplan.train_fetches + list(fetches)
         sched = None
         if num_microbatches == 1:
             state = dict(self._leaf_state(dict(feeds)))

@@ -14,7 +14,10 @@ TPU-native design (not a CUDA port):
     bound (conservative: we rely on @pl.when zero-cost masking here).
 
 Numerics follow the standard streaming softmax: running row max ``m``,
-normalizer ``l`` and accumulator ``acc`` in fp32 scratch.
+normalizer ``l`` and accumulator ``acc`` in fp32 scratch.  Float32
+inputs contract at full fp32 precision (``Precision.HIGHEST``), as XLA's
+attention does under ``jax.default_matmul_precision("highest")``; other
+dtypes take Mosaic's default single pass.
 """
 
 from __future__ import annotations
@@ -26,7 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import tpu_compiler_params
 
 NEG_INF = -1e30
 LANES = 128
@@ -34,7 +36,7 @@ LANES = 128
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             causal: bool, window: int | None, block_q: int, block_k: int,
-            num_kb: int, sm_scale: float):
+            num_kb: int, sm_scale: float, precision):
     qb = pl.program_id(2)
     kb = pl.program_id(3)
 
@@ -49,6 +51,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     v = v_ref[0, 0].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=precision,
                             preferred_element_type=jnp.float32)
     s = s * sm_scale                                  # (bq, bk)
 
@@ -73,6 +76,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                            m_prev.shape)
     acc = acc_scr[...] * alpha[:, :1] \
         + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                              precision=precision,
                               preferred_element_type=jnp.float32)
     m_scr[...] = m_new
     l_scr[...] = l_new
@@ -109,9 +113,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
     grid = (b, h, num_qb, num_kb)
     kern = functools.partial(
         _kernel, causal=causal, window=window, block_q=block_q,
-        block_k=block_k, num_kb=num_kb, sm_scale=sm_scale)
+        block_k=block_k, num_kb=num_kb, sm_scale=sm_scale,
+        precision=jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
+        else None)
 
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
@@ -131,7 +137,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q, d), jnp.float32),       # output accumulator
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-    )(q, k, v)
+    )
+    # Mosaic cannot lower 64-bit scalars (Python ints and floats turn
+    # into them under x64), so the kernel is always traced with 32-bit
+    # defaults, also inside a caller's x64 scope (the exact float64
+    # reduction of a sharded train step is traced in one)
+    with jax.enable_x64(False):
+        return call(q, k, v)

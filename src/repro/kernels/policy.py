@@ -8,10 +8,12 @@ the multi-pod dry-run lowers — Mosaic kernels target real TPUs).
 The graph-IR runtime consumes the same policy through
 :func:`select_attention_impl_per_class`: when ``runtime.program`` lowers
 an ``attention`` op it asks this module — with the device-LOCAL shard
-shapes — whether the Pallas flash kernel applies
-(``kernels.flash_attention``) or the pure-XLA reference must run
-(``kernels.ref.flash_attention_ref``).  The decision is memoized per
-distinct (q, kv) shard-shape pair, so every device of a specialization
+shapes and the platform of the mesh the program is placed on — whether
+the Pallas flash kernel applies (``kernels.flash_attention``) or the
+pure-XLA reference must run (``kernels.ref.flash_attention_ref``).
+Under ``"auto"`` that platform, not the process default backend,
+decides.  The decision is memoized per distinct (q, kv) shard-shape
+pair and platform, so every device of a specialization
 class (``core.lowered_ir``) shares ONE decision and ONE emitted branch;
 it participates in the class partition (same shapes, different impl ⇒
 different classes — can't happen under one policy, but the seam is
@@ -40,13 +42,16 @@ def get_policy() -> str:
     return _POLICY
 
 
-def use_pallas() -> bool:
-    import jax
-    if _POLICY == "pallas":
-        return True
-    if _POLICY == "ref":
-        return False
-    return jax.default_backend() == "tpu"
+def use_pallas(platform: str | None = None) -> bool:
+    """Whether the kernels apply on ``platform`` (the platform of the
+    devices a program is placed on; the process default backend when
+    not given)."""
+    if _POLICY != "auto":
+        return _POLICY == "pallas"
+    if platform is None:
+        import jax
+        platform = jax.default_backend()
+    return platform == "tpu"
 
 
 def attention_eligible(q_shape, kv_shape, *, block_q: int = 128,
@@ -64,24 +69,27 @@ def attention_eligible(q_shape, kv_shape, *, block_q: int = 128,
             and sq % bq == 0 and sk % bk == 0)
 
 
-def select_attention_impl(q_shape, kv_shape) -> str:
+def select_attention_impl(q_shape, kv_shape,
+                          platform: str | None = None) -> str:
     """``"pallas"`` or ``"ref"`` for one device-local attention dispatch
     (the graph-IR lowering seam; see ``runtime.program``)."""
-    if use_pallas() and attention_eligible(q_shape, kv_shape):
+    if use_pallas(platform) and attention_eligible(q_shape, kv_shape):
         return "pallas"
     return "ref"
 
 
-#: (q_shape, kv_shape) -> impl; cleared on set_policy so a policy flip
-#: re-decides every class
+#: (q_shape, kv_shape, platform) -> impl; cleared on set_policy so a
+#: policy flip re-decides every class
 _impl_cache: dict[tuple, str] = {}
 
 
-def select_attention_impl_per_class(q_shape, kv_shape) -> str:
+def select_attention_impl_per_class(q_shape, kv_shape,
+                                    platform: str) -> str:
     """Per-class dispatch: memoized :func:`select_attention_impl` over
-    distinct device-local (q, kv) shard-shape pairs, so all devices of a
-    specialization class resolve to the same kernel with one decision."""
-    key = (tuple(q_shape), tuple(kv_shape))
+    distinct device-local (q, kv) shard-shape pairs on the platform of
+    the program's mesh, so all devices of a specialization class
+    resolve to the same kernel with one decision."""
+    key = (tuple(q_shape), tuple(kv_shape), platform)
     impl = _impl_cache.get(key)
     if impl is None:
         impl = _impl_cache[key] = select_attention_impl(*key)

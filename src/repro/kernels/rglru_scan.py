@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import tpu_compiler_params
 
 _C = 8.0
 
@@ -81,7 +80,7 @@ def rglru_pallas(x, r, i, lam, *, chunk: int = 128, block_w: int = 128,
         out_shape=jax.ShapeDtypeStruct((b, s, w), x.dtype),
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(a, bterm)
     return y

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import tpu_compiler_params
 
 
 def _kernel(la_ref, xbar_ref, b_ref, c_ref, y_ref, state_out_ref, state_scr,
@@ -110,7 +109,7 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = False):
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(la, xbar, B, C)
     return y, state

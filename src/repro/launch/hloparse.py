@@ -40,13 +40,8 @@ def _shape_bytes(expr: str) -> int:
 
 
 def normalize_cost_analysis(cost) -> dict:
-    """``Compiled.cost_analysis()`` returned ``[dict]`` through jax 0.4.x
-    and a plain ``dict`` from 0.5 on; normalize to one flat dict."""
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost)
+    """``Compiled.cost_analysis()`` as a plain dict (``None`` -> empty)."""
+    return dict(cost) if cost is not None else {}
 
 
 def collective_bytes(hlo_text: str) -> dict[str, int]:

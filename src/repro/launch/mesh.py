@@ -29,17 +29,32 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     return Mesh(dev_array, axes)
 
 
+def device_shortfall(what: str, need: int, have: int,
+                     platform: str | None = None) -> str:
+    """Error text for ``what`` spanning ``need`` devices where only
+    ``have`` devices of ``platform`` (default: the default backend) are
+    available.  On the CPU backend more host devices can be forced; the
+    chips of an accelerator cannot, so the text says to fit the layout
+    to them."""
+    platform = platform or jax.default_backend()
+    msg = (f"{what} spans {need} logical devices but only {have} "
+           f"{platform} device(s) are available")
+    if platform == "cpu":
+        return (f"{msg}; force more host devices (e.g. XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={need}, see "
+                f"repro.runtime.harness)")
+    return f"{msg}; use a layout of at most {have} device(s)"
+
+
 def make_runtime_mesh(n_devices: int | None = None, axis: str = "dev") -> Mesh:
     """1-D mesh for the communication-plan execution backend
-    (``repro.runtime``): one axis over the first ``n_devices`` host
-    devices; HSPMD logical device ids map onto axis positions."""
+    (``repro.runtime``): one axis over the first ``n_devices`` devices
+    of the default backend; HSPMD logical device ids map onto axis
+    positions."""
     devices = jax.devices()
     n = n_devices or len(devices)
     if len(devices) < n:
-        raise RuntimeError(
-            f"need {n} devices, found {len(devices)} — run under "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={n} "
-            f"(see repro.runtime.harness)")
+        raise RuntimeError(device_shortfall("the program", n, len(devices)))
     return Mesh(np.array(devices[:n]), (axis,))
 
 

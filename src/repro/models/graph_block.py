@@ -214,3 +214,61 @@ def block_program(cfg, *, batch: int = 4, seq: int = 8,
                 embed=embed, loss_head=loss_head)
     strat = block_strategy(g, dp=dp, tp=tp, pp=pp, name=name)
     return api.Program(g, [strat])
+
+
+def init_block_weights(prog, rng) -> dict:
+    """Seeded float32 weights for a ``block_program``: norm scales at
+    one, everything else ``N(0, 0.05^2)`` drawn from ``rng`` (a numpy
+    ``Generator``) in parameter order."""
+    import numpy as np
+
+    ws = {}
+    for t in prog.graph.parameters():
+        shp = tuple(t.shape)
+        if "norm" in t.name.split("/")[-1]:
+            ws[t.name] = np.ones(shp, np.float32)
+        else:
+            w = rng.standard_normal(shp, dtype=np.float32)
+            w *= np.float32(0.05)
+            ws[t.name] = w
+    return ws
+
+
+def reference_loss(cfg, n_layers: int, ids, labels):
+    """Plain-jax twin of :func:`build_block` over ``models.layers``:
+    the same pre-norm stack (``positions=None``, no RoPE) and the same
+    mean picked-probability loss head.  Returns ``loss(params)`` over
+    the parameter dict :func:`init_block_weights` builds, unsharded —
+    the float32 reference the graph-IR program is checked against."""
+    import jax
+    import jax.numpy as jnp
+
+    from . import layers
+
+    eps = cfg.norm_eps
+
+    def loss(params):
+        x = params["embed"][ids]
+        for i in range(n_layers):
+            p = {k.split("/", 1)[1]: v for k, v in params.items()
+                 if k.startswith(f"l{i}/")}
+            ap = {k: p[k] for k in ("wq", "wk", "wv", "wo")}
+            for bn in ("bq", "bk", "bv"):
+                if bn in p:
+                    ap[bn] = p[bn]
+            h = layers.rms_norm({"w": p["attn_norm"]}, x, eps)
+            y, _ = layers.apply_attention(ap, h, cfg, positions=None,
+                                          causal=True, use_rope=False)
+            x = x + y
+            h = layers.rms_norm({"w": p["mlp_norm"]}, x, eps)
+            x = x + layers.apply_mlp(
+                {"gate": p["w_gate"], "up": p["w_up"],
+                 "down": p["w_down"]}, h, cfg.mlp)
+        x = layers.rms_norm({"w": params["final_norm"]}, x, eps)
+        lm = params["embed"].T if cfg.tie_embeddings \
+            else params["lm_head"]
+        probs = jax.nn.softmax(x @ lm, -1)
+        pl = jnp.take_along_axis(probs, labels[..., None], -1)[..., 0]
+        return pl.mean()
+
+    return loss

@@ -60,7 +60,6 @@ def apply_moe_ep_shmap(p, x, cfg: ModelConfig, mesh):
     Drop policy: capacity is enforced per (batch shard x expert), a
     standard local-capacity variant (exact mode keeps zero drops).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     m = cfg.moe
     b, s, d = x.shape
@@ -123,10 +122,10 @@ def apply_moe_ep_shmap(p, x, cfg: ModelConfig, mesh):
     shared_arg = p.get("shared") if m.n_shared else jnp.zeros(())
     shared_specs = (jtu.tree_map(lambda _: P(None, None, None), p["shared"])
                     if m.n_shared else P())
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(bd, None), P(None, None), experts_specs,
-                             shared_specs),
-                   out_specs=(P(bd, None), P()), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(bd, None), P(None, None), experts_specs,
+                                 shared_specs),
+                       out_specs=(P(bd, None), P()), check_vma=False)
     y, aux = fn(xt, p["router"], p["experts"], shared_arg)
     return y.reshape(b, s, d).astype(x.dtype), aux
 

@@ -168,12 +168,11 @@ class AsyncLoweredGraph:
             mesh = make_runtime_mesh(len(self.order))
         self.mesh = mesh
         self.n_mesh = int(mesh.devices.size)
+        self.platform = mesh.devices.flat[0].platform
         if self.n_mesh < len(self.order):
-            raise ValueError(
-                f"graph spans {len(self.order)} logical devices but mesh "
-                f"has only {self.n_mesh}; force more host devices (e.g. "
-                f"XLA_FLAGS=--xla_force_host_platform_device_count="
-                f"{len(self.order)})")
+            from repro.launch.mesh import device_shortfall
+            raise ValueError(device_shortfall(
+                "graph", len(self.order), self.n_mesh, self.platform))
         self.axis = mesh.axis_names[0]
 
         self.leaves = [o.outputs[0] for o in graph.ops
@@ -200,7 +199,8 @@ class AsyncLoweredGraph:
             ks = shapes[op.inputs[1].name]
             return select_attention_impl_per_class(
                 tuple(op.inputs[0].annots[k].device_shape(dev, qs)),
-                tuple(op.inputs[1].annots[k].device_shape(dev, ks)))
+                tuple(op.inputs[1].annots[k].device_shape(dev, ks)),
+                self.platform)
 
         # bucket the schedulable ops exactly like the simulator's ticks
         buckets: dict[tuple[int, str], list] = {}
@@ -272,7 +272,6 @@ class AsyncLoweredGraph:
 
     def _compile_channel(self, op, trigger) -> CommChannel:
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         pl = self._plan_lowering(op)
@@ -284,8 +283,8 @@ class AsyncLoweredGraph:
             return pl.apply(x, i, x.dtype)[None]
 
         spec = P(axis, *([None] * len(self.shapes[op.inputs[0].name])))
-        jitted = jax.jit(shard_map(body, mesh=self.mesh, in_specs=spec,
-                                   out_specs=spec, check_rep=False))
+        jitted = jax.jit(jax.shard_map(body, mesh=self.mesh, in_specs=spec,
+                                       out_specs=spec, check_vma=False))
         fn = maybe_x64(jitted,
                        pl.needs_x64 and self.reduction == "exact")
         return CommChannel(
@@ -295,7 +294,6 @@ class AsyncLoweredGraph:
     def _compile_bucket(self, key, inline_ops, impl_of
                         ) -> StageProgram | None:
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         if not inline_ops:
@@ -359,17 +357,18 @@ class AsyncLoweredGraph:
                 else:
                     emit_segment(entry, tenv, i, seg_live=seg_live,
                                  graph=graph, k=k, shapes=shapes,
-                                 order=order, n_mesh=n_mesh)
+                                 order=order, n_mesh=n_mesh,
+                                 platform=self.platform)
             return tuple(tenv[n][None] for n in out_names)
 
         in_specs = tuple(P(axis, *([None] * len(shapes[n])))
                          for n in in_names)
         out_specs = tuple(P(axis, *([None] * len(shapes[n])))
                           for n in out_names)
-        jitted = jax.jit(shard_map(body, mesh=self.mesh,
-                                   in_specs=in_specs,
-                                   out_specs=out_specs,
-                                   check_rep=False))
+        jitted = jax.jit(jax.shard_map(body, mesh=self.mesh,
+                                       in_specs=in_specs,
+                                       out_specs=out_specs,
+                                       check_vma=False))
         fn = maybe_x64(jitted, needs_x64 and self.reduction == "exact")
         return StageProgram(key[0], key[1], list(inline_ops), in_names,
                             out_names, fn,

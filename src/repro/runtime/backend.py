@@ -47,11 +47,10 @@ class CompiledPlan:
         self.order = DeviceOrder.for_plan(plan)
         self.n_mesh = int(mesh.devices.size)
         if self.n_mesh < len(self.order):
-            raise ValueError(
-                f"plan spans {len(self.order)} logical devices but mesh "
-                f"has only {self.n_mesh}; force more host devices (e.g. "
-                f"XLA_FLAGS=--xla_force_host_platform_device_count="
-                f"{len(self.order)})")
+            from repro.launch.mesh import device_shortfall
+            raise ValueError(device_shortfall(
+                "plan", len(self.order), self.n_mesh,
+                mesh.devices.flat[0].platform))
         self.stats = LoweringStats()
         self.fn = lower_plan(plan, self.shape, mesh, self.order,
                              reduction=reduction, stats_out=self.stats)

@@ -28,8 +28,11 @@ def host_device_env(n_devices: int = DEFAULT_DEVICES,
                     base: dict | None = None) -> dict:
     """Environment for a child process that must see ``n_devices`` host
     devices (existing XLA_FLAGS are preserved, any prior force-count flag
-    is replaced)."""
+    is replaced).  The child is held to the CPU backend
+    (``JAX_PLATFORMS=cpu``): on a machine with an accelerator it must
+    never reach for a chip that its parent may hold."""
     env = dict(os.environ if base is None else base)
+    env["JAX_PLATFORMS"] = "cpu"
     flags = [f for f in env.get("XLA_FLAGS", "").split()
              if not f.startswith(FORCE_FLAG)]
     flags.append(f"{FORCE_FLAG}={n_devices}")

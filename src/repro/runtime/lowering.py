@@ -229,11 +229,8 @@ class PlanLowering:
         if plan.src is None:
             raise ValueError("plan has no source annotation")
         if n_mesh < len(order):
-            raise ValueError(
-                f"plan spans {len(order)} logical devices but mesh has "
-                f"only {n_mesh}; force more host devices (e.g. "
-                f"XLA_FLAGS=--xla_force_host_platform_device_count="
-                f"{len(order)})")
+            from repro.launch.mesh import device_shortfall
+            raise ValueError(device_shortfall("plan", len(order), n_mesh))
         self.plan = plan
         self.shape = tuple(shape)
         self.order = order
@@ -748,10 +745,10 @@ def maybe_x64(fn, needs_x64: bool):
     is traced (keyed into the jit cache; process defaults untouched)."""
     if not needs_x64:
         return fn
-    from jax.experimental import enable_x64
+    import jax
 
     def run_x64(*args):
-        with enable_x64():
+        with jax.enable_x64(True):
             return fn(*args)
 
     return run_x64
@@ -772,7 +769,6 @@ def lower_plan(plan: CommPlan, shape: tuple[int, ...], mesh,
     the batched-permute fusion micro-benchmark measures against.
     """
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     order = order or DeviceOrder.for_plan(plan)
@@ -791,6 +787,6 @@ def lower_plan(plan: CommPlan, shape: tuple[int, ...], mesh,
 
     rank = len(shape)
     spec = P(axis, *([None] * rank))
-    jitted = jax.jit(shard_map(body, mesh=mesh, in_specs=spec,
-                               out_specs=spec, check_rep=False))
+    jitted = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=spec,
+                                   out_specs=spec, check_vma=False))
     return maybe_x64(jitted, lowering.needs_x64 and reduction == "exact")

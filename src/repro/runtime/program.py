@@ -96,10 +96,13 @@ def segment_liveness(graph: Graph, segments, fetches
     return out
 
 
-def run_segment_class(seg, cls, dtypes, live_in, live_out, out_pads, vs):
+def run_segment_class(seg, cls, dtypes, live_in, live_out, out_pads, vs,
+                      platform: str):
     """Trace one class's local program over the segment: slice live-ins
     to the class's exact local shapes once, keep every interior value
-    unpadded, re-pad only the live-outs."""
+    unpadded, re-pad only the live-outs.  ``platform`` is that of the
+    mesh the program is placed on: Pallas kernels compile for ``"tpu"``
+    and run through the interpreter anywhere else."""
     import jax.numpy as jnp
 
     local = dict(zip(live_in, vs))
@@ -117,10 +120,9 @@ def run_segment_class(seg, cls, dtypes, live_in, live_out, out_pads, vs):
             ins.append(v)
         name = op.outputs[0].name
         if spec.impl == "pallas":
-            from repro.kernels.ops import attention as attn_kernel
-            y = attn_kernel(*ins,
-                            causal=op.attrs.get("causal", True),
-                            use_kernel="pallas")
+            from repro.kernels.flash_attention import flash_attention
+            y = flash_attention(*ins, causal=op.attrs.get("causal", True),
+                                interpret=platform != "tpu")
         else:
             y = local_apply(op.kind, jnp, ins, op.attrs, spec.out_shape)
         exact[name] = y.astype(dtypes[name])
@@ -139,7 +141,8 @@ def run_segment_class(seg, cls, dtypes, live_in, live_out, out_pads, vs):
 
 
 def emit_segment(seg, tenv, i, *, seg_live, graph: Graph, k: int,
-                 shapes, order: DeviceOrder, n_mesh: int) -> None:
+                 shapes, order: DeviceOrder, n_mesh: int,
+                 platform: str) -> None:
     """Emit one compute segment into the traced env ``tenv``: one branch
     per specialization class (straight-line when homogeneous over the
     whole mesh), plus a zero branch when some mesh position idles."""
@@ -169,11 +172,12 @@ def emit_segment(seg, tenv, i, *, seg_live, graph: Graph, k: int,
         pos_cls.append(n_cls if c is None else c)
     if n_cls == 1 and all(c == 0 for c in pos_cls):
         outs = run_segment_class(seg, seg.classes[0], dtypes, live_in,
-                                 live_out, out_pads, args)
+                                 live_out, out_pads, args, platform)
     else:
         branches = [
             (lambda cls: lambda *vs: run_segment_class(
-                seg, cls, dtypes, live_in, live_out, out_pads, vs))(cls)
+                seg, cls, dtypes, live_in, live_out, out_pads, vs,
+                platform))(cls)
             for cls in seg.classes]
         if any(c == n_cls for c in pos_cls):
             branches.append(lambda *vs: tuple(
@@ -190,26 +194,23 @@ def fetch_rows(outs, n_mesh: int) -> list:
     On the CPU backend each per-device shard is host memory already, so
     ``np.from_dlpack`` views it without the stitch-and-copy that
     ``jax.device_get`` performs on a sharded array (the DLPack capsule
-    keeps the jax buffer alive for as long as the views are).  Falls
-    back to one bulk ``device_get`` elsewhere."""
+    keeps the jax buffer alive for as long as the views are).  On an
+    accelerator the shards live in device memory, and one bulk
+    ``device_get`` copies them all to the host."""
     import jax
 
-    try:
-        per_out = []
-        for out in outs:
-            rows: list = [None] * n_mesh
-            for sh in out.addressable_shards:
-                idx = sh.index[0]
-                pos = (idx.start or 0) if isinstance(idx, slice) \
-                    else int(idx)
-                rows[pos] = np.from_dlpack(sh.data)[0]
-            if any(r is None for r in rows):
-                raise ValueError("unaddressable shard")
-            per_out.append(rows)
-        return per_out
-    except Exception:
+    if not outs or outs[0].addressable_shards[0].device.platform != "cpu":
         return [[arr[i] for i in range(n_mesh)]
                 for arr in jax.device_get(outs)]
+    per_out = []
+    for out in outs:
+        rows: list = [None] * n_mesh
+        for sh in out.addressable_shards:
+            idx = sh.index[0]
+            pos = (idx.start or 0) if isinstance(idx, slice) else int(idx)
+            rows[pos] = np.from_dlpack(sh.data)[0]
+        per_out.append(rows)
+    return per_out
 
 
 def unpack_rows(graph: Graph, k: int, shapes, order: DeviceOrder,
@@ -257,7 +258,6 @@ class LoweredGraph:
                  reduction: str = "exact", fetches=None,
                  num_microbatches: int = 1):
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         self.graph = graph
@@ -293,12 +293,12 @@ class LoweredGraph:
             mesh = make_runtime_mesh(len(self.order))
         self.mesh = mesh
         self.n_mesh = int(mesh.devices.size)
+        # the placed devices, not the process default, pick the kernel
+        platform = mesh.devices.flat[0].platform
         if self.n_mesh < len(self.order):
-            raise ValueError(
-                f"graph spans {len(self.order)} logical devices but mesh "
-                f"has only {self.n_mesh}; force more host devices (e.g. "
-                f"XLA_FLAGS=--xla_force_host_platform_device_count="
-                f"{len(self.order)})")
+            from repro.launch.mesh import device_shortfall
+            raise ValueError(device_shortfall(
+                "graph", len(self.order), self.n_mesh, platform))
         axis = mesh.axis_names[0]
 
         self.leaves = [o.outputs[0] for o in graph.ops
@@ -336,7 +336,8 @@ class LoweredGraph:
             ks = shapes[op.inputs[1].name]
             return select_attention_impl_per_class(
                 tuple(op.inputs[0].annots[k].device_shape(dev, qs)),
-                tuple(op.inputs[1].annots[k].device_shape(dev, ks)))
+                tuple(op.inputs[1].annots[k].device_shape(dev, ks)),
+                platform)
 
         self.ir = partition_graph(graph, strategy, shapes=shapes,
                                   impl_of=impl_of,
@@ -465,7 +466,8 @@ class LoweredGraph:
                         flush(pend)
                     emit_segment(entry, tenv, i, seg_live=seg_live,
                                  graph=graph, k=k, shapes=shapes,
-                                 order=order, n_mesh=n_mesh)
+                                 order=order, n_mesh=n_mesh,
+                                 platform=platform)
             flush()
             return tenv
 
@@ -497,9 +499,40 @@ class LoweredGraph:
                     for f in self.fetches}
         out_specs = tuple(P(axis, *([None] * out_rank[f]))
                           for f in self.fetches)
-        jitted = jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                                   out_specs=out_specs, check_rep=False))
-        self.fn = maybe_x64(jitted, needs_x64 and reduction == "exact")
+        self._jitted = jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False))
+        self._x64 = needs_x64 and reduction == "exact"
+        self.fn = maybe_x64(self._jitted, self._x64)
+
+    def lower(self, dtypes):
+        """Ahead-of-time lowering of the step program from shapes alone:
+        ``dtypes`` maps every leaf name to its dtype, and each argument
+        becomes a ``ShapeDtypeStruct`` placed on the mesh.  No array is
+        made, so the mesh may be a described device topology that is
+        not attached.  ``.compile()`` on the result gives
+        ``memory_analysis()`` and the compiled text, and a later call
+        with arrays of these dtypes reuses that executable."""
+        import contextlib
+
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        axis = self.mesh.axis_names[0]
+        args = []
+        for t in self.leaves:
+            mb = (self.num_microbatches,) \
+                if self.num_microbatches > 1 and t.name in self._per_mb \
+                else ()
+            shape = (self.n_mesh,) + mb + pad_shape(
+                t.annots[self.k], self.shapes[t.name])
+            args.append(jax.ShapeDtypeStruct(
+                shape, np.dtype(dtypes[t.name]),
+                sharding=NamedSharding(
+                    self.mesh, P(axis, *([None] * (len(shape) - 1))))))
+        with jax.enable_x64(True) if self._x64 \
+                else contextlib.nullcontext():
+            return self._jitted.lower(*args)
 
     # -- introspection (lazy: not on the lowering/execution path) ----------
 

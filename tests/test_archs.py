@@ -136,59 +136,12 @@ def test_param_counts_roughly_match_model_cards():
 # graph-IR transformer block vs the plain-jax layers reference
 # ---------------------------------------------------------------------------
 
-def _block_reference_loss(cfg, L, ids, labels):
-    """Plain-jax twin of ``models.graph_block.build_block``: the same
-    pre-norm stack via ``models.layers`` (positions=None, no RoPE) and
-    the same mean-picked-probability loss head."""
-    from repro.models import layers
-
-    eps = cfg.norm_eps
-
-    def loss(params):
-        x = params["embed"][ids]
-        for i in range(L):
-            p = {k.split("/", 1)[1]: v for k, v in params.items()
-                 if k.startswith(f"l{i}/")}
-            ap = {k: p[k] for k in ("wq", "wk", "wv", "wo")}
-            for bn in ("bq", "bk", "bv"):
-                if bn in p:
-                    ap[bn] = p[bn]
-            h = layers.rms_norm({"w": p["attn_norm"]}, x, eps)
-            y, _ = layers.apply_attention(ap, h, cfg, positions=None,
-                                          causal=True, use_rope=False)
-            x = x + y
-            h = layers.rms_norm({"w": p["mlp_norm"]}, x, eps)
-            x = x + layers.apply_mlp(
-                {"gate": p["w_gate"], "up": p["w_up"],
-                 "down": p["w_down"]}, h, cfg.mlp)
-        x = layers.rms_norm({"w": params["final_norm"]}, x, eps)
-        lm = params["embed"].T if cfg.tie_embeddings \
-            else params["lm_head"]
-        probs = jax.nn.softmax(x @ lm, -1)
-        pl = jnp.take_along_axis(probs, labels[..., None], -1)[..., 0]
-        return pl.mean()
-
-    return loss
-
-
 def _block_fixture(arch, *, B=2, S=8, seed=0):
-    from repro.models.graph_block import block_program
-
     cfg = get_config(arch).reduced()
     rng = np.random.default_rng(seed)
     ids = rng.integers(0, cfg.vocab, (B, S)).astype(np.int32)
     labels = rng.integers(0, cfg.vocab, (B, S)).astype(np.int32)
     return cfg, rng, ids, labels
-
-
-def _init_block_weights(prog, rng):
-    ws = {}
-    for t in prog.graph.parameters():
-        shp = tuple(t.shape)
-        ws[t.name] = np.ones(shp, np.float32) \
-            if "norm" in t.name.split("/")[-1] \
-            else (rng.standard_normal(shp) * 0.05).astype(np.float32)
-    return ws
 
 
 @pytest.mark.parametrize("arch,par", [
@@ -202,18 +155,19 @@ def test_graph_block_fwd_bwd_matches_layers_reference(arch, par):
     mathematically zero — softmax is shift-invariant along the key
     axis — so comparisons need the absolute floor, not pure rtol)."""
     from repro import api
-    from repro.models.graph_block import block_program
+    from repro.models.graph_block import (block_program, init_block_weights,
+                                          reference_loss)
 
     cfg, rng, ids, labels = _block_fixture(arch)
     prog = block_program(cfg, batch=2, seq=8, **par)
-    ws = _init_block_weights(prog, rng)
+    ws = init_block_weights(prog, rng)
 
     sess = api.Session(prog, 0, executor=api.SimulatorExecutor())
     sess.load(ws)
     r = sess.train_step({"ids": ids, "labels": labels},
                         num_microbatches=1)
 
-    loss = _block_reference_loss(cfg, cfg.n_layers, ids, labels)
+    loss = reference_loss(cfg, cfg.n_layers, ids, labels)
     want, grads = jax.value_and_grad(loss)(
         {n: jnp.asarray(v) for n, v in ws.items()})
     np.testing.assert_allclose(r.loss, float(want), rtol=1e-5, atol=1e-9)
@@ -227,18 +181,19 @@ def test_graph_block_single_device_jax_matches_reference():
     """Same differential on the real JaxExecutor (single device, so it
     runs in-process without forced host devices)."""
     from repro import api
-    from repro.models.graph_block import block_program
+    from repro.models.graph_block import (block_program, init_block_weights,
+                                          reference_loss)
 
     cfg, rng, ids, labels = _block_fixture("qwen2_1_5b", seed=1)
     prog = block_program(cfg, batch=2, seq=8, dp=1, tp=1, pp=1)
-    ws = _init_block_weights(prog, rng)
+    ws = init_block_weights(prog, rng)
 
     sess = api.Session(prog, 0, executor=api.JaxExecutor())
     sess.load(ws)
     r = sess.train_step({"ids": ids, "labels": labels},
                         num_microbatches=1)
 
-    loss = _block_reference_loss(cfg, cfg.n_layers, ids, labels)
+    loss = reference_loss(cfg, cfg.n_layers, ids, labels)
     want, grads = jax.value_and_grad(loss)(
         {n: jnp.asarray(v) for n, v in ws.items()})
     np.testing.assert_allclose(r.loss, float(want), rtol=1e-5, atol=1e-9)

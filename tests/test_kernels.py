@@ -203,3 +203,19 @@ def test_select_attention_impl_honours_policy_and_eligibility():
         assert policy.select_attention_impl(ok_q, ok_kv) == "ref"
     finally:
         policy.set_policy("auto")
+
+
+@pytest.mark.parametrize("platform,want", [("tpu", "pallas"),
+                                           ("cpu", "ref")])
+def test_auto_policy_follows_the_mesh_platform(platform, want):
+    """Under "auto" the platform of the devices a program is placed on
+    picks the kernel, whatever backend the process defaults to."""
+    from repro.kernels import policy
+
+    ok_q, ok_kv = (1, 4, 128, 64), (1, 2, 128, 64)
+    assert policy.get_policy() == "auto"
+    assert policy.select_attention_impl_per_class(ok_q, ok_kv,
+                                                  platform) == want
+    # ineligible shards stay on the reference on every platform
+    assert policy.select_attention_impl_per_class(
+        (1, 4, 128, 60), (1, 2, 128, 60), platform) == "ref"

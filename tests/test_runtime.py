@@ -230,6 +230,83 @@ def test_execute_plan_rejects_bad_shard_shape():
                      make_runtime_mesh(1))
 
 
+def test_host_device_env_holds_children_to_cpu():
+    """A forced-host-device child never reaches for a chip its parent
+    may hold: its environment pins the CPU backend."""
+    from repro.runtime.harness import FORCE_FLAG, host_device_env
+
+    env = host_device_env(4, base={"JAX_PLATFORMS": "tpu",
+                                   "XLA_FLAGS": f"{FORCE_FLAG}=2 --x"})
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert env["XLA_FLAGS"].split() == ["--x", f"{FORCE_FLAG}=4"]
+
+
+@pytest.mark.parametrize("platform,advice", [
+    ("cpu", "--xla_force_host_platform_device_count=4"),
+    ("tpu", "use a layout of at most 1 device(s)"),
+])
+def test_device_shortfall_names_the_fix_per_platform(platform, advice):
+    from repro.launch.mesh import device_shortfall
+
+    msg = device_shortfall("graph", 4, 1, platform)
+    assert f"only 1 {platform} device(s)" in msg and advice in msg
+
+
+def test_lowered_graph_on_too_small_mesh_names_the_shortfall():
+    from repro.configs import get_config
+    from repro.launch.mesh import make_runtime_mesh
+    from repro.models.graph_block import block_program
+    from repro.runtime.program import LoweredGraph
+
+    cfg = get_config("qwen2-1.5b").reduced()
+    tplan = block_program(cfg, batch=2, seq=8, dp=2, tp=1).compile_train(0)
+    with pytest.raises(ValueError, match="spans 2 logical devices"):
+        LoweredGraph(tplan.graph, mesh=make_runtime_mesh(1))
+
+
+@pytest.mark.parametrize("placed", [True, False])
+def test_compile_cache_goes_where_the_environment_says(tmp_path, placed):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX's cache is written there
+    and nowhere else; without it, it goes to the fixed in-checkout
+    directory (checked without compiling, so the test leaves the
+    checkout clean)."""
+    import subprocess
+    import sys
+
+    from repro.runtime.compile_cache import DEFAULT_DIR
+    from repro.runtime.harness import host_device_env
+
+    src = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.runtime.compile_cache import use_compile_cache\n"
+        "path = use_compile_cache()\n"
+        "print(path)\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        "if path != jax.config.jax_compilation_cache_dir:\n"
+        "    raise SystemExit(1)\n"
+        f"if {placed}:\n"
+        "    jax.config.update("
+        "'jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "    jax.jit(lambda x: x * 2 + 1)(jnp.ones(8)).block_until_ready()\n")
+    env = host_device_env(1)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if placed:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "x")
+    proc = subprocess.run([sys.executable, "-c", src], env=env,
+                          capture_output=True, text=True, timeout=120,
+                          cwd=str(tmp_path))
+    assert proc.returncode == 0, proc.stderr
+    lines = proc.stdout.split()
+    if placed:
+        assert lines == [str(tmp_path / "x")] * 2
+        assert any((tmp_path / "x").iterdir())
+        assert [p.name for p in tmp_path.iterdir()] == ["x"]
+    else:
+        assert lines == [DEFAULT_DIR] * 2
+        assert DEFAULT_DIR.endswith(".jax_cache")
+        assert not any(tmp_path.iterdir())
+
+
 def test_device_items_matches_specialize():
     """The runtime's per-device view of a plan lists exactly the comm
     ExecItems progressive specialization gives that device (Fig 9)."""
