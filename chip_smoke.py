@@ -12,7 +12,8 @@ cut is printed.  Weights and tokens are random, drawn from ``SEED``.
 
 One chip (the default): the step program is compiled ahead of time to
 read its size (``memory_analysis``) and to check that the Pallas flash
-kernel is in it, then 3 steps run.  Step 0's loss and gradients are
+kernel is in it, then 3 steps run.  The steps must compile nothing
+more, so the executable checked is the one that ran.  Step 0's loss and gradients are
 compared with the plain float32 ``models.layers`` twin of the block
 (``graph_block.reference_loss``).  Both run with float32 matmuls
 (``jax.default_matmul_precision("highest")``).  The same reference at
@@ -101,10 +102,52 @@ def check_kernel_compiled(text: str) -> None:
           "the compiled step holds no Pallas kernel (tpu_custom_call)")
 
 
+class StepWatch:
+    """What the train steps ran: the seconds of each call of the step
+    program (from the call to its outputs being ready, so waiting for
+    the inputs' transfer is in it), the devices holding its outputs,
+    and every executable JAX compiled or read from its persistent cache
+    meanwhile (each records ``COMPILE_EVENT``)."""
+
+    COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self, lw):
+        self.calls, self.devices, self.compiled = [], set(), []
+        self._lw, self._run = lw, lw.fn
+        lw.fn = self._call
+
+    def _call(self, *args):
+        import jax
+
+        t0 = time.perf_counter()
+        outs = jax.block_until_ready(self._run(*args))
+        self.calls.append(time.perf_counter() - t0)
+        self.devices.update(sh.device for o in outs
+                            for sh in o.addressable_shards)
+        return outs
+
+    def _event(self, event, secs, fun_name="?", **_):
+        if event == self.COMPILE_EVENT:
+            self.compiled.append(fun_name)
+
+    def __enter__(self):
+        import jax
+
+        jax.monitoring.register_event_duration_secs_listener(self._event)
+        return self
+
+    def __exit__(self, *exc):
+        import jax
+
+        jax.monitoring.unregister_event_duration_listener(self._event)
+        self._lw.fn = self._run
+
+
 def compile_step(ex, tplan, label: str, limit: int):
-    """Compile the train step ahead of time (a later ``train_step``
-    reuses the executable), print its size and attention tallies, and
-    check that it fits and that attention runs on the Pallas kernel."""
+    """Compile the train step ahead of time, print its size and
+    attention tallies, and check that it fits and that attention runs
+    on the Pallas kernel.  ``train_step`` then runs this executable
+    (checked by ``train_steps``)."""
     lw = ex.lowered(tplan, tplan.train_fetches)
     t0 = time.perf_counter()
     compiled = lw.lower(leaf_dtypes(lw)).compile()
@@ -133,20 +176,36 @@ def compile_step(ex, tplan, label: str, limit: int):
     return lw
 
 
-def train_steps(sess, feeds, steps: int, label: str):
-    """Run ``steps`` train steps; returns step 0's TrainResult."""
+def train_steps(sess, lw, devices, feeds, steps: int, label: str):
+    """Run ``steps`` train steps of the compiled step ``lw``; check that
+    they compile nothing and that their outputs are held on every one
+    of ``devices``.  Returns step 0's TrainResult."""
     first = None
-    for i in range(steps):
-        t0 = time.perf_counter()
-        r = sess.train_step(dict(feeds))
-        dt = time.perf_counter() - t0
-        print(f"[{label}] step {i}: loss {r.loss:.9g}, grad norm "
-              f"{r.metrics['grad_norm']:.6g} (smoke timing, one step: "
-              f"{dt:.2f} s wall)")
-        check(np.isfinite(r.loss) and np.isfinite(r.metrics["grad_norm"]),
-              f"[{label}] step {i} is not finite")
-        if first is None:
-            first = r
+    with StepWatch(lw) as watch:
+        for i in range(steps):
+            t0 = time.perf_counter()
+            r = sess.train_step(dict(feeds))
+            dt = time.perf_counter() - t0
+            print(f"[{label}] step {i}: loss {r.loss:.9g}, grad norm "
+                  f"{r.metrics['grad_norm']:.6g} (smoke timing, one "
+                  f"step: {dt:.2f} s wall, of which the step program's "
+                  f"call {watch.calls[-1]:.2f} s)")
+            check(np.isfinite(r.loss)
+                  and np.isfinite(r.metrics["grad_norm"]),
+                  f"[{label}] step {i} is not finite")
+            if first is None:
+                first = r
+    check(len(watch.calls) == steps,
+          f"[{label}] {steps} steps made {len(watch.calls)} calls of the "
+          f"compiled step")
+    check(not watch.compiled,
+          f"[{label}] the steps compiled {watch.compiled}: the executable "
+          f"checked is not the one that ran")
+    check(watch.devices == set(devices),
+          f"[{label}] step outputs on {sorted(map(str, watch.devices))}, "
+          f"not on all of {sorted(map(str, devices))}")
+    print(f"[{label}] the steps compiled nothing; outputs held on "
+          f"{len(watch.devices)} chip(s)")
     return first
 
 
@@ -236,8 +295,8 @@ def one_chip(cfg, devices) -> None:
           f"{SEQ} tokens)")
     with jax.default_matmul_precision("highest"):
         ex, sess, tplan = session(prog, ws, devices[:1])
-        compile_step(ex, tplan, "1 chip", memory_limit(devices[0]))
-        first = train_steps(sess, feeds, STEPS, "1 chip")
+        lw = compile_step(ex, tplan, "1 chip", memory_limit(devices[0]))
+        first = train_steps(sess, lw, devices[:1], feeds, STEPS, "1 chip")
     grads = {n: first.grad_value(n) for n in ws}
     ref_loss, ref_grads = reference(cfg, ws, feeds["ids"],
                                     feeds["labels"], "highest")
@@ -268,20 +327,7 @@ def four_chip(cfg, devices) -> None:
             prog, ws, feeds = setup(cfg, dp, tp)
             ex, sess, tplan = session(prog, ws, devs)
             lw = compile_step(ex, tplan, label, limit)
-            run, seen = lw.fn, set()
-
-            def recording(*args, run=run, seen=seen):
-                outs = run(*args)
-                seen.update(sh.device for o in outs
-                            for sh in o.addressable_shards)
-                return outs
-
-            lw.fn = recording
-            first = train_steps(sess, feeds, 1, label)
-            check(seen == set(devs),
-                  f"[{label}] step outputs on {sorted(map(str, seen))}, "
-                  f"not on all of {sorted(map(str, devs))}")
-            print(f"[{label}] step outputs held on {len(seen)} chip(s)")
+            first = train_steps(sess, lw, devs, feeds, 1, label)
             results[label] = (first.loss,
                               {n: first.grad_value(n) for n in ws})
             del sess, ex, lw, first
@@ -308,7 +354,7 @@ def main(argv=None) -> int:
         print(f"chip_smoke: needs a TPU, found platform {dev.platform!r} "
               f"({dev.device_kind}); nothing was run", file=sys.stderr)
         return 2
-    from repro.runtime.compile_cache import use_compile_cache
+    from repro.runtime.harness import use_compile_cache
 
     print(f"device: {dev.device_kind} ({dev.platform}), "
           f"{len(devices)} chip(s); compile cache {use_compile_cache()}")

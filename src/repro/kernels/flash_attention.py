@@ -14,10 +14,9 @@ TPU-native design (not a CUDA port):
     bound (conservative: we rely on @pl.when zero-cost masking here).
 
 Numerics follow the standard streaming softmax: running row max ``m``,
-normalizer ``l`` and accumulator ``acc`` in fp32 scratch.  Float32
-inputs contract at full fp32 precision (``Precision.HIGHEST``), as XLA's
-attention does under ``jax.default_matmul_precision("highest")``; other
-dtypes take Mosaic's default single pass.
+normalizer ``l`` and accumulator ``acc`` in fp32 scratch.  The two
+matmuls follow the caller's ``jax.default_matmul_precision`` like the
+XLA matmuls around the kernel (:func:`contract_precision`).
 """
 
 from __future__ import annotations
@@ -32,6 +31,24 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128
+
+
+def contract_precision(dtype):
+    """Precision of the kernel's matmuls, read when it is traced (the
+    setting is part of the jit key).  Mosaic contracts at one bfloat16
+    pass (its default, ``None``) or at fp32: float32 inputs take fp32
+    when ``jax.default_matmul_precision`` asks for more than one pass,
+    and one pass otherwise, as XLA's float32 matmuls do on a TPU.  Other
+    dtypes always take the default."""
+    if jnp.dtype(dtype) != jnp.float32:
+        return None
+    try:
+        asked = jax.lax.Precision(jax.config.jax_default_matmul_precision)
+    except ValueError:          # a DotAlgorithmPreset name
+        asked = None
+    if asked == jax.lax.Precision.DEFAULT:
+        return None
+    return jax.lax.Precision.HIGHEST
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -114,8 +131,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     kern = functools.partial(
         _kernel, causal=causal, window=window, block_q=block_q,
         block_k=block_k, num_kb=num_kb, sm_scale=sm_scale,
-        precision=jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
-        else None)
+        precision=contract_precision(q.dtype))
 
     call = pl.pallas_call(
         kern,

@@ -6,8 +6,8 @@ be created with ``jax.vmap`` over per-layer keys and executed with
 ``jax.lax.scan`` (compact HLO — essential for 512-way GSPMD partitioning
 of 80-95 layer models).
 
-Attention runs through :mod:`repro.kernels.ops` which dispatches between
-the pure-XLA reference and the Pallas TPU kernels.
+Attention (:func:`sdpa`) takes the Pallas flash kernel where
+:mod:`repro.kernels.policy` allows it and the pure-XLA math otherwise.
 """
 
 from __future__ import annotations

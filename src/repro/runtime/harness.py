@@ -24,6 +24,24 @@ def _repo_root() -> str:
         os.path.dirname(os.path.abspath(__file__)))))
 
 
+def use_compile_cache() -> str:
+    """Turn JAX's persistent compilation cache on and return its
+    directory: the one ``JAX_COMPILATION_CACHE_DIR`` names (JAX reads
+    that variable itself, and nothing else is set), else ``.jax_cache``
+    at the root of this checkout.  The path is part of what makes an
+    entry findable by a later process, so it is fixed.  Call it before
+    the process's first compile: JAX fixes the cache when it first uses
+    it."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+
+    path = os.path.join(_repo_root(), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def host_device_env(n_devices: int = DEFAULT_DEVICES,
                     base: dict | None = None) -> dict:
     """Environment for a child process that must see ``n_devices`` host

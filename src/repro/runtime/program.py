@@ -511,14 +511,12 @@ class LoweredGraph:
         becomes a ``ShapeDtypeStruct`` placed on the mesh.  No array is
         made, so the mesh may be a described device topology that is
         not attached.  ``.compile()`` on the result gives
-        ``memory_analysis()`` and the compiled text, and a later call
-        with arrays of these dtypes reuses that executable."""
-        import contextlib
-
+        ``memory_analysis()`` and the compiled text.  The lowering is
+        the one a call traces (same placement, same x64 scope), so in
+        the same process a later call with arrays of these dtypes runs
+        that executable without compiling again."""
         import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
 
-        axis = self.mesh.axis_names[0]
         args = []
         for t in self.leaves:
             mb = (self.num_microbatches,) \
@@ -528,11 +526,8 @@ class LoweredGraph:
                 t.annots[self.k], self.shapes[t.name])
             args.append(jax.ShapeDtypeStruct(
                 shape, np.dtype(dtypes[t.name]),
-                sharding=NamedSharding(
-                    self.mesh, P(axis, *([None] * (len(shape) - 1))))))
-        with jax.enable_x64(True) if self._x64 \
-                else contextlib.nullcontext():
-            return self._jitted.lower(*args)
+                sharding=self._block_sharding(len(shape))))
+        return maybe_x64(self._jitted.lower, self._x64)(*args)
 
     # -- introspection (lazy: not on the lowering/execution path) ----------
 
@@ -574,16 +569,20 @@ class LoweredGraph:
     def _put(self, stacked: np.ndarray):
         return self._put_all([stacked])[0]
 
+    def _block_sharding(self, ndim: int):
+        """Placement of a stacked leaf block: row ``p`` on mesh position
+        ``p``, the rest of the block whole on that device."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        return NamedSharding(self.mesh, P(self.mesh.axis_names[0],
+                                          *([None] * (ndim - 1))))
+
     def _put_all(self, blocks: list[np.ndarray]):
         """One batched ``device_put`` for all leaf blocks."""
         import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
 
-        axis = self.mesh.axis_names[0]
-        shardings = [
-            NamedSharding(self.mesh, P(axis, *([None] * (b.ndim - 1))))
-            for b in blocks]
-        return jax.device_put(blocks, shardings)
+        return jax.device_put(
+            blocks, [self._block_sharding(b.ndim) for b in blocks])
 
     def _unpack(self, name: str, rows: list) -> ShardedTensor:
         return unpack_rows(self.graph, self.k, self.shapes, self.order,

@@ -9,7 +9,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ref import flash_attention_ref, rglru_ref, ssd_scan_ref
 from repro.kernels.rglru_scan import rglru_pallas
@@ -157,7 +156,7 @@ def test_rglru_matches_stepwise_decode():
 
 
 # ---------------------------------------------------------------------------
-# dispatch wrappers
+# dispatch: the two implementations an attention op can lower onto
 # ---------------------------------------------------------------------------
 
 def test_ops_dispatch_ref_vs_pallas():
@@ -165,9 +164,26 @@ def test_ops_dispatch_ref_vs_pallas():
     q = jax.random.normal(ks[0], (1, 2, 128, 64))
     k = jax.random.normal(ks[1], (1, 2, 128, 64))
     v = jax.random.normal(ks[2], (1, 2, 128, 64))
-    a = ops.attention(q, k, v, use_kernel="ref")
-    b = ops.attention(q, k, v, use_kernel="pallas", interpret=True)
+    a = flash_attention_ref(q, k, v)
+    b = flash_attention(q, k, v, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+
+
+@pytest.mark.parametrize("setting,expected", [
+    (None, None), ("default", None), ("bfloat16", None),
+    ("highest", jax.lax.Precision.HIGHEST),
+    ("float32", jax.lax.Precision.HIGHEST),
+    ("tensorfloat32", jax.lax.Precision.HIGHEST),
+])
+def test_flash_kernel_follows_default_matmul_precision(setting, expected):
+    """f32 inputs contract as the caller's matmul precision asks: one
+    pass by default, fp32 when more than one pass is asked for (Mosaic
+    offers those two); other dtypes always take Mosaic's default."""
+    from repro.kernels.flash_attention import contract_precision
+
+    with jax.default_matmul_precision(setting):
+        assert contract_precision(jnp.float32) == expected
+        assert contract_precision(jnp.bfloat16) is None
 
 
 def test_set_policy_rejects_unknown_policy():

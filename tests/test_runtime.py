@@ -270,15 +270,15 @@ def test_compile_cache_goes_where_the_environment_says(tmp_path, placed):
     and nowhere else; without it, it goes to the fixed in-checkout
     directory (checked without compiling, so the test leaves the
     checkout clean)."""
+    import os
     import subprocess
     import sys
 
-    from repro.runtime.compile_cache import DEFAULT_DIR
-    from repro.runtime.harness import host_device_env
+    from repro.runtime.harness import _repo_root, host_device_env
 
     src = (
         "import jax, jax.numpy as jnp\n"
-        "from repro.runtime.compile_cache import use_compile_cache\n"
+        "from repro.runtime.harness import use_compile_cache\n"
         "path = use_compile_cache()\n"
         "print(path)\n"
         "print(jax.config.jax_compilation_cache_dir)\n"
@@ -302,9 +302,57 @@ def test_compile_cache_goes_where_the_environment_says(tmp_path, placed):
         assert any((tmp_path / "x").iterdir())
         assert [p.name for p in tmp_path.iterdir()] == ["x"]
     else:
-        assert lines == [DEFAULT_DIR] * 2
-        assert DEFAULT_DIR.endswith(".jax_cache")
+        assert lines == [os.path.join(_repo_root(), ".jax_cache")] * 2
         assert not any(tmp_path.iterdir())
+
+
+_AOT_REUSE_SRC = """
+import jax, numpy as np
+from jax.sharding import Mesh
+from repro import api
+from repro.configs import get_config
+from repro.models.graph_block import block_program, init_block_weights
+
+compiled = []
+def on_event(event, secs, fun_name='?', **_):
+    if event == '/jax/core/compile/backend_compile_duration':
+        compiled.append(fun_name)
+
+cfg = get_config('qwen2-1.5b').reduced()
+for dp, tp in ((1, 1), (2, 2)):
+    prog = block_program(cfg, batch=2, seq=8, n_layers=1, dp=dp, tp=tp,
+                         pp=1)
+    rng = np.random.default_rng(0)
+    ws = init_block_weights(prog, rng)
+    feeds = {n: rng.integers(0, cfg.vocab, (2, 8), np.int32)
+             for n in ('ids', 'labels')}
+    ex = api.JaxExecutor(mesh=Mesh(np.array(jax.devices()[:dp * tp]),
+                                   ('dev',)))
+    sess = api.Session(prog, 0, executor=ex)
+    sess.load(ws)
+    tplan = prog.compile_train(0)
+    lw = ex.lowered(tplan, tplan.train_fetches)
+    lw.lower({t.name: np.int32 if t.name in feeds else np.float32
+              for t in lw.leaves}).compile()
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    for _ in range(2):
+        sess.train_step(dict(feeds))
+    jax.monitoring.unregister_event_duration_listener(on_event)
+    print(f'dp{dp}tp{tp} x64={lw._x64} compiled={compiled}')
+"""
+
+
+def test_ahead_of_time_lowering_is_what_a_step_runs():
+    """``LoweredGraph.lower`` places and scopes its arguments as a call
+    does, so once it is compiled the train steps compile nothing more:
+    the executable an ahead-of-time check reads is the one that runs.
+    dp2tp2 takes the exact float64 fold, traced in an x64 scope."""
+    from repro.runtime.harness import run_subprocess
+
+    proc = run_subprocess(_AOT_REUSE_SRC, n_devices=4, timeout=300)
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.split("\n")[:2] == [
+        "dp1tp1 x64=False compiled=[]", "dp2tp2 x64=True compiled=[]"]
 
 
 def test_device_items_matches_specialize():

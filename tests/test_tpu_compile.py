@@ -9,8 +9,9 @@ Nothing runs, so nothing here says anything about results or times.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
-imports this file.  Where the topology cannot be described the tests
-skip.  The persistent compile cache is off around them: an entry
+imports this file.  The tests skip only where the TPU compiler
+(``libtpu``) is not installed; any other failure to describe the
+topology fails them.  The persistent compile cache is off around them: an entry
 compiled for a described chip cannot be read back without one.
 """
 
@@ -26,16 +27,15 @@ HBM_BYTES = 16e9        # one TPU v5e chip
 
 @pytest.fixture(scope="module")
 def topo():
+    pytest.importorskip("libtpu", reason="the TPU compiler is not "
+                                         "installed")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
-    try:
-        desc = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — any failure means: no TPU here
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    desc = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
     before = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -92,6 +92,24 @@ def test_flash_kernel_compiles_at_qwen2_widths(topo):
     kv = jax.ShapeDtypeStruct((2, K, 1024, HD), jnp.float32, sharding=one)
     compiled = flash_attention.lower(q, kv, kv, causal=True,
                                      interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("setting", ["default", "tensorfloat32",
+                                     "highest"])
+def test_flash_kernel_compiles_under_each_matmul_precision(topo, setting):
+    """Mosaic contracts at one pass or at fp32 only; a setting between
+    the two ("tensorfloat32", three passes in XLA) must still lower."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from repro.kernels.flash_attention import flash_attention
+
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((1, 2, 256, HD), jnp.float32, sharding=one)
+    with jax.default_matmul_precision(setting):
+        compiled = flash_attention.lower(q, q, q, causal=True).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
