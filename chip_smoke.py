@@ -13,9 +13,10 @@ cut is printed.  Weights and tokens are random, drawn from ``SEED``.
 One chip (the default): the step program is compiled ahead of time to
 read its size (``memory_analysis``) and to check that the Pallas flash
 kernel is in it, then 3 steps run.  The steps must compile nothing
-more, so the executable checked is the one that ran.  Step 0's loss and gradients are
-compared with the plain float32 ``models.layers`` twin of the block
-(``graph_block.reference_loss``).  Both run with float32 matmuls
+more, so the executable checked is the one that ran.  Step 0's loss
+and gradients are compared with the plain float32 ``models.layers``
+twin of the block (``graph_block.reference_loss``).  Both run with
+float32 matmuls
 (``jax.default_matmul_precision("highest")``).  The same reference at
 the chip's default precision (one bfloat16 pass per matmul) must then
 miss the tolerance: that shows the comparison can tell the two apart.
