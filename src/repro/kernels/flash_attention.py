@@ -36,19 +36,25 @@ LANES = 128
 def contract_precision(dtype):
     """Precision of the kernel's matmuls, read when it is traced (the
     setting is part of the jit key).  Mosaic contracts at one bfloat16
-    pass (its default, ``None``) or at fp32: float32 inputs take fp32
-    when ``jax.default_matmul_precision`` asks for more than one pass,
-    and one pass otherwise, as XLA's float32 matmuls do on a TPU.  Other
-    dtypes always take the default."""
+    pass (``DEFAULT``) or at fp32 (``HIGHEST``): float32 inputs take
+    fp32 when ``jax.default_matmul_precision`` asks for more than one
+    pass, and one pass otherwise, as XLA's float32 matmuls do on a TPU.
+    Other dtypes always take one pass.  A ``DotAlgorithmPreset`` name
+    asks for one pass when its operands are 16-bit or narrower and it
+    names no pass count (``BF16_BF16_F32``), for more otherwise
+    (``F32_F32_F32``, ``BF16_BF16_F32_X6``).  The result is always
+    explicit, since a preset left to ``lax.dot_general``'s default
+    does not lower in Mosaic."""
+    one = jax.lax.Precision.DEFAULT
     if jnp.dtype(dtype) != jnp.float32:
-        return None
+        return one
+    setting = jax.config.jax_default_matmul_precision
     try:
-        asked = jax.lax.Precision(jax.config.jax_default_matmul_precision)
+        one_pass = jax.lax.Precision(setting) == one
     except ValueError:          # a DotAlgorithmPreset name
-        asked = None
-    if asked == jax.lax.Precision.DEFAULT:
-        return None
-    return jax.lax.Precision.HIGHEST
+        one_pass = ("_X" not in setting
+                    and not setting.startswith(("F32", "TF32", "F64")))
+    return one if one_pass else jax.lax.Precision.HIGHEST
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,

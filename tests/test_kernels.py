@@ -169,21 +169,25 @@ def test_ops_dispatch_ref_vs_pallas():
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
 
 
-@pytest.mark.parametrize("setting,expected", [
-    (None, None), ("default", None), ("bfloat16", None),
-    ("highest", jax.lax.Precision.HIGHEST),
-    ("float32", jax.lax.Precision.HIGHEST),
-    ("tensorfloat32", jax.lax.Precision.HIGHEST),
+@pytest.mark.parametrize("setting,fp32", [
+    (None, False), ("default", False), ("bfloat16", False),
+    ("highest", True), ("float32", True), ("tensorfloat32", True),
+    ("BF16_BF16_F32", False), ("ANY_F8_ANY_F8_F32", False),
+    ("BF16_BF16_F32_X6", True), ("TF32_TF32_F32", True),
+    ("F32_F32_F32", True),
 ])
-def test_flash_kernel_follows_default_matmul_precision(setting, expected):
+def test_flash_kernel_follows_default_matmul_precision(setting, fp32):
     """f32 inputs contract as the caller's matmul precision asks: one
     pass by default, fp32 when more than one pass is asked for (Mosaic
-    offers those two); other dtypes always take Mosaic's default."""
+    offers those two); other dtypes always take one pass.  The choice is
+    explicit, so no preset reaches Mosaic."""
     from repro.kernels.flash_attention import contract_precision
 
+    P = jax.lax.Precision
     with jax.default_matmul_precision(setting):
-        assert contract_precision(jnp.float32) == expected
-        assert contract_precision(jnp.bfloat16) is None
+        assert contract_precision(jnp.float32) == (
+            P.HIGHEST if fp32 else P.DEFAULT)
+        assert contract_precision(jnp.bfloat16) == P.DEFAULT
 
 
 def test_set_policy_rejects_unknown_policy():

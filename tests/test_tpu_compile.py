@@ -96,10 +96,11 @@ def test_flash_kernel_compiles_at_qwen2_widths(topo):
 
 
 @pytest.mark.parametrize("setting", ["default", "tensorfloat32",
-                                     "highest"])
+                                     "highest", "BF16_BF16_F32"])
 def test_flash_kernel_compiles_under_each_matmul_precision(topo, setting):
     """Mosaic contracts at one pass or at fp32 only; a setting between
-    the two ("tensorfloat32", three passes in XLA) must still lower."""
+    the two ("tensorfloat32", three passes in XLA) must still lower, and
+    so must a dot-algorithm preset, which Mosaic cannot take."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
