@@ -11,7 +11,6 @@ bytes over fast/slow links, planning + estimated transfer time).
 
 from __future__ import annotations
 
-import statistics
 import time
 from dataclasses import dataclass
 from typing import Mapping, Sequence
@@ -25,6 +24,7 @@ from repro.core.simulator import ShardedTensor, gather, scatter
 from repro.core.switching import SwitchReport
 from repro.core.switching import switch as core_switch
 from repro.core.topology import Topology
+from repro.runtime import telemetry
 
 from .executors import Executor, JaxExecutor, SimulatorExecutor
 from .program import CompiledPlan, Program
@@ -81,19 +81,6 @@ class RunResult:
 
     def values(self) -> dict[str, np.ndarray]:
         return {name: self.value(name) for name in self.outputs}
-
-
-@dataclass
-class MeasuredStep:
-    """A timed :meth:`Session.measure_train_step` outcome."""
-
-    seconds: float                   # median wall time per step
-    result: TrainResult              # first measured step
-    # per-(stage, phase) tick timings, one {device: [per-op seconds]}
-    # per executed tick, pooled across repeats (None unless the
-    # executor records ticks)
-    tick_device_seconds: dict[tuple[int, str],
-                              list[dict[int, list[float]]]] | None = None
 
 
 class Session:
@@ -230,23 +217,24 @@ class Session:
             v = 1
         sched = mplan.schedule(mplan.num_microbatches, schedule,
                                virtual_stages_per_device=v)
-        micro_feeds = self._split_feeds(feeds, mplan)
         states = []
-        for j in range(mplan.num_microbatches):
-            st: dict[str, ShardedTensor] = {}
-            for t in mplan.graph.placeholders():
-                annot = mplan.graph.tensors[t.name].annots[
-                    mplan.strategy_index]
-                st[t.name] = scatter(
-                    micro_feeds[j][t.name], annot,
-                    rng=np.random.default_rng(self.seed))
-            for t in mplan.graph.parameters():
-                if t.name not in self.weights:
-                    raise ValueError(
-                        f"parameter {t.name!r} not loaded; call "
-                        f"session.load")
-                st[t.name] = self.weights[t.name]
-            states.append(st)
+        with telemetry.span("feed.shard"):
+            micro_feeds = self._split_feeds(feeds, mplan)
+            for j in range(mplan.num_microbatches):
+                st: dict[str, ShardedTensor] = {}
+                for t in mplan.graph.placeholders():
+                    annot = mplan.graph.tensors[t.name].annots[
+                        mplan.strategy_index]
+                    st[t.name] = scatter(
+                        micro_feeds[j][t.name], annot,
+                        rng=np.random.default_rng(self.seed))
+                for t in mplan.graph.parameters():
+                    if t.name not in self.weights:
+                        raise ValueError(
+                            f"parameter {t.name!r} not loaded; call "
+                            f"session.load")
+                    st[t.name] = self.weights[t.name]
+                states.append(st)
         if hasattr(self.executor, "run_schedule"):
             per_mb = self.executor.run_schedule(mplan, sched, states,
                                                 fetches)
@@ -292,86 +280,66 @@ class Session:
         ``loss`` defaults to the graph's single scalar sink; ``fetches``
         may name extra tensors (activations, activation grads via
         ``plan.grad_map``) to return on ``TrainResult.outputs``.
+
+        Each step leaves a record in ``runtime.telemetry.recent_steps()``
+        with its ``hspmd.*`` spans (``feed.shard``, the executor's feed,
+        call and fetch, ``optimizer``), the bytes it moved and the host
+        state it keeps (``docs/training.md``).
         """
         from repro.optim.adamw import (AdamWConfig, init_sharded_state,
                                        sharded_apply_updates)
 
-        feeds = dict(feeds or {})
-        self._validate_schedule_kind(schedule, virtual_stages_per_device)
-        if self.optimizer is None:
-            self.optimizer = AdamWConfig()
-        k = self.plan.strategy_index
-        tplan = self.program.compile_train(
-            k, loss=loss, num_microbatches=num_microbatches,
-            shape_env=self.shape_env, topology=self.topology)
-        params = [t.name for t in tplan.graph.parameters()]
-        for name in params:
-            if name not in self.weights:
-                raise ValueError(
-                    f"parameter {name!r} not loaded; call session.load")
-        grad_fetch = [tplan.grad_map[p] for p in params]
-        fetch_list = tplan.train_fetches + list(fetches)
-        sched = None
-        if num_microbatches == 1:
-            state = dict(self._leaf_state(dict(feeds)))
-            outs = self.executor.run(tplan, state, fetch_list)
-        else:
-            per_mb, sched = self._run_pipelined(
-                tplan, feeds, fetch_list, schedule,
-                virtual_stages_per_device)
-            full = self.program.compile_train(
-                k, loss=loss, shape_env=self.shape_env,
-                topology=self.topology)
-            outs = self._combine(per_mb, tplan, full_plan=full)
-        loss_value = float(gather(outs[tplan.loss_name]))
-        grads = {p: outs[g] for p, g in zip(params, grad_fetch)}
-        if self.opt_state is None:
-            self.opt_state = init_sharded_state(self.weights)
-        self.weights, self.opt_state, metrics = sharded_apply_updates(
-            self.weights, grads, self.opt_state, self.optimizer)
+        updates = (self.opt_state["count"] if self.opt_state else 0) + 1
+        with telemetry.step(updates):
+            feeds = dict(feeds or {})
+            self._validate_schedule_kind(schedule, virtual_stages_per_device)
+            if self.optimizer is None:
+                self.optimizer = AdamWConfig()
+            k = self.plan.strategy_index
+            tplan = self.program.compile_train(
+                k, loss=loss, num_microbatches=num_microbatches,
+                shape_env=self.shape_env, topology=self.topology)
+            params = [t.name for t in tplan.graph.parameters()]
+            for name in params:
+                if name not in self.weights:
+                    raise ValueError(
+                        f"parameter {name!r} not loaded; call session.load")
+            grad_fetch = [tplan.grad_map[p] for p in params]
+            fetch_list = tplan.train_fetches + list(fetches)
+            sched = None
+            if num_microbatches == 1:
+                state = dict(self._leaf_state(dict(feeds)))
+                outs = self.executor.run(tplan, state, fetch_list)
+            else:
+                per_mb, sched = self._run_pipelined(
+                    tplan, feeds, fetch_list, schedule,
+                    virtual_stages_per_device)
+                full = self.program.compile_train(
+                    k, loss=loss, shape_env=self.shape_env,
+                    topology=self.topology)
+                outs = self._combine(per_mb, tplan, full_plan=full)
+            loss_value = float(gather(outs[tplan.loss_name]))
+            grads = {p: outs[g] for p, g in zip(params, grad_fetch)}
+            with telemetry.span("optimizer"):
+                if self.opt_state is None:
+                    self.opt_state = init_sharded_state(self.weights)
+                self.weights, self.opt_state, metrics = \
+                    sharded_apply_updates(self.weights, grads,
+                                          self.opt_state, self.optimizer)
+            telemetry.hold(self.weights, self.opt_state)
         metrics["loss"] = loss_value
         extra = {f: outs[f] for f in fetches}
         return TrainResult(loss_value, grads, metrics, schedule=sched,
                            outputs=extra)
 
-    def measure_train_step(self, feeds: Mapping[str, object] | None = None,
-                           *, repeats: int = 3, warmup: int = 1,
-                           **train_kw) -> "MeasuredStep":
-        """Run :meth:`train_step` ``warmup + repeats`` times and report
-        the median wall seconds of the measured calls, plus — when the
-        executor records per-tick device timings
-        (``SimulatorExecutor(record_ticks=True)``) — the per-(stage,
-        phase) tick timings pooled across repeats, which the search
-        validator re-prices into a parallel makespan.  Weights DO
-        advance (each call is a real optimizer step); ``result`` is the
-        first measured step's :class:`TrainResult`."""
-        walls: list[float] = []
-        ticks: dict[tuple[int, str], list[dict[int, float]]] = {}
-        result: TrainResult | None = None
-        for i in range(warmup + repeats):
-            t0 = time.perf_counter()
-            r = self.train_step(feeds, **train_kw)
-            dt = time.perf_counter() - t0
-            if i < warmup:
-                continue
-            walls.append(dt)
-            if result is None:
-                result = r
-            rec = getattr(self.executor, "last_tick_device_seconds",
-                          None)
-            if rec:
-                for key, occurrences in rec.items():
-                    ticks.setdefault(key, []).extend(occurrences)
-        assert result is not None  # repeats >= 1
-        return MeasuredStep(statistics.median(walls), result,
-                            ticks or None)
-
     def _leaf_state(self, feeds: dict) -> dict[str, ShardedTensor]:
         state: dict[str, ShardedTensor] = {}
-        for t in self.program.graph.placeholders():
-            if t.name not in feeds:
-                raise ValueError(f"missing feed for placeholder {t.name!r}")
-            state[t.name] = self._shard(t.name, feeds.pop(t.name))
+        with telemetry.span("feed.shard"):
+            for t in self.program.graph.placeholders():
+                if t.name not in feeds:
+                    raise ValueError(
+                        f"missing feed for placeholder {t.name!r}")
+                state[t.name] = self._shard(t.name, feeds.pop(t.name))
         if feeds:
             raise ValueError(f"unknown feeds {sorted(feeds)}")
         for t in self.program.graph.parameters():

@@ -316,20 +316,23 @@ def test_measure_train_step_and_recorded_ticks():
     from repro import api
     from repro.api.testing import loss_pipeline_program, \
         loss_pipeline_values
+    from repro.runtime.telemetry import recent_steps
 
     prog = loss_pipeline_program(2, name="pipe2")
     xv, ws, want_y = loss_pipeline_values(seed=11)
-    sess = api.Session(prog, "pipe2",
-                       executor=api.SimulatorExecutor(record_ticks=True))
+    ex = api.SimulatorExecutor(record_ticks=True)
+    sess = api.Session(prog, "pipe2", executor=ex)
     sess.load(ws)
-    ms = sess.measure_train_step({"X": xv}, repeats=2,
-                                 num_microbatches=4)
-    assert ms.seconds > 0
-    # the warmup step already applied an optimizer update, so the
-    # measured step's loss has moved off the fresh-weights value
-    assert np.isfinite(ms.result.loss)
-    assert ms.tick_device_seconds
-    for (stage, phase), occurrences in ms.tick_device_seconds.items():
+    sess.train_step({"X": xv}, num_microbatches=4)
+    r = sess.train_step({"X": xv}, num_microbatches=4)
+    # the first step already applied an optimizer update, so the
+    # second step's loss has moved off the fresh-weights value
+    assert np.isfinite(r.loss)
+    rec = recent_steps()[-1]
+    assert rec.updates == 2 and rec.seconds > 0
+    assert rec.spans["optimizer"] <= rec.seconds
+    assert ex.last_tick_device_seconds
+    for (stage, phase), occurrences in ex.last_tick_device_seconds.items():
         assert phase in ("fwd", "bwd")
         for devops in occurrences:
             for dev, samples in devops.items():
