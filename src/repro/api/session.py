@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import Mapping, Sequence
+from typing import Callable, Mapping, Sequence
 
 import numpy as np
 
@@ -35,13 +35,22 @@ from .strategy import Strategy
 class TrainResult:
     """One training step's outcome: the (global) loss, the gradient
     shards that produced the update, optimizer metrics (grad_norm, lr),
-    and — for microbatched steps — the executed pipeline timetable."""
+    and — for microbatched steps — the executed pipeline timetable.
+
+    Where the update ran on the device the gradients stay there until
+    ``grads`` is first read, which fetches them."""
 
     loss: float
-    grads: dict[str, ShardedTensor]
+    _grads: "dict[str, ShardedTensor] | Callable[[], dict]"
     metrics: dict[str, float]
     schedule: PipelineSchedule | None = None
     outputs: dict[str, ShardedTensor] | None = None  # extra fetches
+
+    @property
+    def grads(self) -> dict[str, ShardedTensor]:
+        if callable(self._grads):
+            self._grads = self._grads()
+        return self._grads
 
     @property
     def stats(self) -> "ScheduleStats | None":
@@ -102,12 +111,31 @@ class Session:
         # training state (train_step): AdamW config + sharded m/v/count,
         # created lazily on the first step and resharded by switch()
         self.optimizer = optimizer
-        self.opt_state: dict | None = None
+        self._opt: dict | None = None
 
     # -- state -------------------------------------------------------------
     @property
     def strategy(self) -> Strategy:
         return self.plan.strategy
+
+    @property
+    def opt_state(self) -> dict | None:
+        """AdamW state: None before the first step, else ``{"m": {name:
+        ShardedTensor}, "v": {...}, "count": int}`` with m/v sharded like
+        the weights.  State the device holds is copied to host views on
+        each read.  Assigning a dict of host shards (a restore) or None
+        (dropping the state, on the device too) replaces it."""
+        from repro.optim import adamw
+
+        st = self._opt
+        if st is None or not isinstance(st["m"], adamw.Blocks):
+            return st
+        return {"m": st["m"].host(), "v": st["v"].host(),
+                "count": st["count"]}
+
+    @opt_state.setter
+    def opt_state(self, value: dict | None) -> None:
+        self._opt = value
 
     def _shard(self, name: str, value) -> ShardedTensor:
         if isinstance(value, ShardedTensor):
@@ -275,7 +303,10 @@ class Session:
         replicated params, reduce-scatter over the DP dim for Split
         params), so the AdamW update (``optim.adamw.sharded_apply_
         updates``) is elementwise per shard; optimizer state mirrors the
-        weight sharding and is migrated by :meth:`switch`.
+        weight sharding and is migrated by :meth:`switch`.  An
+        unpipelined step on a JaxExecutor updates the placed weight
+        blocks on the device, against m/v resident there, and fetches
+        the new weights; every other step updates host shards.
 
         ``loss`` defaults to the graph's single scalar sink; ``fetches``
         may name extra tensors (activations, activation grads via
@@ -286,15 +317,14 @@ class Session:
         call and fetch, ``optimizer``), the bytes it moved and the host
         state it keeps (``docs/training.md``).
         """
-        from repro.optim.adamw import (AdamWConfig, init_sharded_state,
-                                       sharded_apply_updates)
+        from repro.optim import adamw
 
-        updates = (self.opt_state["count"] if self.opt_state else 0) + 1
+        updates = (self._opt["count"] if self._opt else 0) + 1
         with telemetry.step(updates):
             feeds = dict(feeds or {})
             self._validate_schedule_kind(schedule, virtual_stages_per_device)
             if self.optimizer is None:
-                self.optimizer = AdamWConfig()
+                self.optimizer = adamw.AdamWConfig()
             k = self.plan.strategy_index
             tplan = self.program.compile_train(
                 k, loss=loss, num_microbatches=num_microbatches,
@@ -304,11 +334,21 @@ class Session:
                 if name not in self.weights:
                     raise ValueError(
                         f"parameter {name!r} not loaded; call session.load")
-            grad_fetch = [tplan.grad_map[p] for p in params]
             fetch_list = tplan.train_fetches + list(fetches)
             sched = None
             if num_microbatches == 1:
                 state = dict(self._leaf_state(dict(feeds)))
+                if isinstance(self.executor, JaxExecutor):
+                    lw = self.executor.lowered(tplan, fetch_list)
+                    layout = adamw.block_layout(
+                        lw.order, lw.n_mesh, lw.mesh,
+                        tuple((n, lw.graph.tensors[n].annots[k],
+                               lw.shapes[n]) for n in sorted(params)))
+                    if not layout.partial:
+                        result = self._device_step(lw, layout, tplan, state,
+                                                   list(fetches))
+                        telemetry.hold(self.weights)
+                        return result
                 outs = self.executor.run(tplan, state, fetch_list)
             else:
                 per_mb, sched = self._run_pipelined(
@@ -319,18 +359,74 @@ class Session:
                     topology=self.topology)
                 outs = self._combine(per_mb, tplan, full_plan=full)
             loss_value = float(gather(outs[tplan.loss_name]))
-            grads = {p: outs[g] for p, g in zip(params, grad_fetch)}
+            grads = {p: outs[tplan.grad_map[p]] for p in params}
             with telemetry.span("optimizer"):
-                if self.opt_state is None:
-                    self.opt_state = init_sharded_state(self.weights)
-                self.weights, self.opt_state, metrics = \
-                    sharded_apply_updates(self.weights, grads,
-                                          self.opt_state, self.optimizer)
-            telemetry.hold(self.weights, self.opt_state)
+                opt = self.opt_state or adamw.init_sharded_state(
+                    self.weights)
+                self.weights, self._opt, metrics = \
+                    adamw.sharded_apply_updates(self.weights, grads, opt,
+                                                self.optimizer)
+            telemetry.hold(self.weights, self._opt)
         metrics["loss"] = loss_value
         extra = {f: outs[f] for f in fetches}
         return TrainResult(loss_value, grads, metrics, schedule=sched,
                            outputs=extra)
+
+    def _device_step(self, lw, layout, tplan: CompiledPlan, state: dict,
+                     fetches: list[str]) -> TrainResult:
+        """The unpipelined step with AdamW on the device: pack, put and
+        call the step program, update the placed weight blocks from its
+        gradient blocks against m/v resident on the mesh, then fetch the
+        loss and the new weights (not the gradients, which
+        ``TrainResult.grads`` fetches when read)."""
+        from repro.optim import adamw
+
+        grad_of = {p: tplan.grad_map[p] for p in layout.names}
+        kept: dict = {}
+
+        def update(placed, outs):
+            p = adamw.Blocks(layout, ((n, placed[n]) for n in layout.names))
+            g = adamw.Blocks(layout, ((n, outs[grad_of[n]])
+                                      for n in layout.names))
+            with telemetry.span("optimizer"):
+                opt = self._device_opt(p)
+                new_p, self._opt, kept["metrics"] = \
+                    adamw.sharded_apply_updates(p, g, opt, self.optimizer)
+            kept["grads"] = g
+            return {tplan.loss_name: outs[tplan.loss_name],
+                    **{f: outs[f] for f in fetches}, **new_p}
+
+        got = lw.run(state, update)
+        self.weights = {n: got[n] for n in layout.names}
+        loss_value = float(gather(got[tplan.loss_name]))
+        g = kept.pop("grads")
+
+        def fetch_grads():
+            from repro.runtime.program import fetch_rows
+
+            rows = fetch_rows([g[n] for n in layout.names], lw.n_mesh)
+            return {n: lw._unpack(grad_of[n], r)
+                    for n, r in zip(layout.names, rows)}
+
+        metrics = dict(kept["metrics"], loss=loss_value)
+        return TrainResult(loss_value, fetch_grads, metrics,
+                           outputs={f: got[f] for f in fetches})
+
+    def _device_opt(self, params) -> dict:
+        """The optimizer state as blocks under ``params``' layout (an
+        ``adamw.Blocks``): kept from the last step, uploaded from host
+        shards, or fresh."""
+        from repro.optim import adamw
+
+        st = self._opt
+        if st is None:
+            return adamw.init_sharded_state(params)
+        layout = params.layout
+        if isinstance(st["m"], adamw.Blocks) and st["m"].layout is layout:
+            return st
+        host = self.opt_state
+        return {"m": layout.put(host["m"]), "v": layout.put(host["v"]),
+                "count": host["count"]}
 
     def _leaf_state(self, feeds: dict) -> dict[str, ShardedTensor]:
         state: dict[str, ShardedTensor] = {}
@@ -415,16 +511,18 @@ class Session:
         outcome = core_switch(
             self.weights, self.program.graph, src, dst, self.shape_env,
             topology, backend=backend, mesh=mesh)
-        if self.opt_state is not None:
+        opt = self.opt_state
+        if opt is not None:
             # optimizer m/v mirror the weight annotations: migrate them
             # through the same fused-BSR plan so training resumes
             # restart-free after the switch
             from repro.core.switching import execute_switch
             for key in ("m", "v"):
-                self.opt_state[key] = execute_switch(
-                    self.opt_state[key], self.program.graph, src, dst,
+                opt[key] = execute_switch(
+                    opt[key], self.program.graph, src, dst,
                     self.shape_env, topology, backend=backend, mesh=mesh,
                     report=outcome.report)
+            self.opt_state = opt
         self.weights = outcome.weights
         self.plan = self.program.compile(dst, shape_env=self.shape_env,
                                          topology=self.topology)

@@ -201,14 +201,12 @@ class ElasticDriver:
         sess = self.session
         tree: dict = {"weights": {n: gather(st)
                                   for n, st in sess.weights.items()}}
-        if sess.opt_state is not None:
+        opt = sess.opt_state
+        if opt is not None:
             tree["opt"] = {
-                "m": {n: gather(st)
-                      for n, st in sess.opt_state["m"].items()},
-                "v": {n: gather(st)
-                      for n, st in sess.opt_state["v"].items()},
-                "count": np.asarray(sess.opt_state["count"],
-                                    dtype=np.int64),
+                "m": {n: gather(st) for n, st in opt["m"].items()},
+                "v": {n: gather(st) for n, st in opt["v"].items()},
+                "count": np.asarray(opt["count"], dtype=np.int64),
             }
         return tree
 

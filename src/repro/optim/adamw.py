@@ -7,16 +7,20 @@ with the ZeRO-1 variant (states sharded, params replicated) selectable by
 the sharding rules.  The paper's elastic scenarios (§7.2) disable
 optimizer-state sharding for restart-free fault tolerance; that maps here
 to passing fully-replicated state specs.
+
+Both the pytree update (:func:`apply_updates`) and the update over
+sharded weights (:func:`sharded_apply_updates`) apply one elementwise
+step, :func:`adamw_leaf`, in float32.
 """
 
 from __future__ import annotations
 
-import dataclasses
+import functools
 from dataclasses import dataclass
-from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @dataclass(frozen=True)
@@ -44,6 +48,18 @@ def _schedule(cfg: AdamWConfig, count):
     return cfg.lr * warm
 
 
+def adamw_leaf(p, g, m, v, scale, lr, bc1, bc2, b1, omb1, b2, omb2, eps,
+               wd):
+    """One leaf's AdamW step in float32 on the clipped gradient
+    ``g * scale``: bias-corrected moments and decoupled weight decay.
+    Returns ``(new p, new m, new v)``; ``p`` keeps its dtype."""
+    g = g.astype(jnp.float32) * scale
+    m = b1 * m + omb1 * g
+    v = b2 * v + omb2 * g * g
+    step = (m / bc1) / (jnp.sqrt(v / bc2) + eps) + wd * p.astype(jnp.float32)
+    return (p.astype(jnp.float32) - lr * step).astype(p.dtype), m, v
+
+
 def apply_updates(params, grads, opt_state, cfg: AdamWConfig):
     """Returns (new_params, new_opt_state, metrics)."""
     count = opt_state["count"] + 1
@@ -51,23 +67,18 @@ def apply_updates(params, grads, opt_state, cfg: AdamWConfig):
     gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
                          for g in jax.tree.leaves(grads)))
     scale = jnp.minimum(1.0, cfg.grad_clip / (gnorm + 1e-9))
-    grads = jax.tree.map(lambda g: g.astype(jnp.float32) * scale, grads)
-
-    m = jax.tree.map(lambda m_, g: cfg.b1 * m_ + (1 - cfg.b1) * g,
-                     opt_state["m"], grads)
-    v = jax.tree.map(lambda v_, g: cfg.b2 * v_ + (1 - cfg.b2) * g * g,
-                     opt_state["v"], grads)
     c = count.astype(jnp.float32)
     bc1 = 1 - cfg.b1 ** c
     bc2 = 1 - cfg.b2 ** c
     lr = _schedule(cfg, count)
 
-    def upd(p, m_, v_):
-        step = (m_ / bc1) / (jnp.sqrt(v_ / bc2) + cfg.eps)
-        step = step + cfg.weight_decay * p.astype(jnp.float32)
-        return (p.astype(jnp.float32) - lr * step).astype(p.dtype)
-
-    new_params = jax.tree.map(upd, params, m, v)
+    out = jax.tree.map(
+        lambda p, g, m_, v_: adamw_leaf(
+            p, g, m_, v_, scale, lr, bc1, bc2, cfg.b1, 1 - cfg.b1, cfg.b2,
+            1 - cfg.b2, cfg.eps, cfg.weight_decay),
+        params, grads, opt_state["m"], opt_state["v"])
+    new_params, m, v = jax.tree.transpose(
+        jax.tree.structure(params), jax.tree.structure((0, 0, 0)), out)
     return new_params, {"m": m, "v": v, "count": count}, \
         {"grad_norm": gnorm, "lr": lr}
 
@@ -78,21 +89,234 @@ def apply_updates(params, grads, opt_state, cfg: AdamWConfig):
 #
 # The graph-IR training step produces gradients as per-device
 # ShardedTensors whose annotations MATCH the parameters' (backward's
-# grad-reduce comm guarantees it: Partial grads are all-reduced /
-# reduce-scattered onto the parameter placement).  The update is
-# therefore elementwise per shard — replicas stay bitwise in sync
-# because every device applies identical numpy arithmetic to identical
-# inputs, which is also what makes the sim and jax executors'
-# train_steps bit-comparable.  The math mirrors ``apply_updates`` above
-# (same clip, warmup, bias correction and decoupled weight decay), so a
-# single-device session matches jax.grad + apply_updates to float
-# tolerance.
+# grad-reduce comm guarantees it), so the update is elementwise per
+# shard.  Every path runs it as the same two jitted programs over the
+# runtime's stacked ``(n_mesh, *pad)`` blocks, whose row ``p`` holds
+# the shard of mesh position ``p``:
+#
+# * ``_row_squares``: each row's sum of squared gradient, per leaf;
+# * ``_row_update``: the global norm from those sums, each tile once,
+#   then :func:`adamw_leaf` on the row.
+#
+# Blocks on a device mesh (the JaxExecutor's unpipelined step) run each
+# program once over the mesh, with m/v resident there.  Host shards
+# (every other path) run them one row at a time on a one-device CPU
+# mesh.  A row sees the same ``(1, *pad)`` program either way, so the
+# two agree bit for bit: a numpy copy of the step would not, since XLA
+# contracts its multiply-adds.  The norm sums the per-tile sums in one
+# order fixed by the annotations (leaves by name, tiles by device),
+# whichever rows hold them.
+
+@functools.lru_cache(maxsize=64)
+def block_layout(order, n_mesh: int, mesh, items: tuple) -> "BlockLayout":
+    """The (shared) :class:`BlockLayout` of ``items``, a tuple of
+    ``(name, annotation, shape)``."""
+    return BlockLayout(order, n_mesh, mesh, items)
+
+
+class BlockLayout:
+    """Where named sharded tensors sit in stacked ``(n_mesh, *pad)``
+    blocks: row ``order.pos(dev)`` holds device ``dev``'s shard at the
+    origin, zero-padded to the elementwise-max shard shape
+    (``runtime.lowering.pack_shards``).  ``mesh`` is the 1-D device mesh
+    the blocks live on, None for host blocks.
+
+    ``rows``/``cols`` pick, leaf by leaf (``cols`` indexes ``names``),
+    one row holding each distinct tile; ``bounds`` maps the index of
+    each leaf whose shards differ in shape to its rows' shard shapes;
+    ``partial`` names the leaves whose shards are summands, which have
+    no tiles."""
+
+    def __init__(self, order, n_mesh: int, mesh, items: tuple):
+        from repro.core.annotations import DUP
+        from repro.runtime.lowering import pad_shape
+
+        self.order, self.n_mesh, self.mesh = order, n_mesh, mesh
+        self.annots = {n: a for n, a, _ in items}
+        self.shapes = {n: tuple(s) for n, _, s in items}
+        self.names = sorted(self.annots)
+        self.pads = {n: pad_shape(self.annots[n], self.shapes[n])
+                     for n in self.names}
+        self.partial = [n for n in self.names if self.annots[n].has_partial]
+        rows, cols = [], []
+        self.bounds: dict[int, np.ndarray] = {}
+        for i, name in enumerate(self.names):
+            annot, shape = self.annots[name], self.shapes[name]
+            if annot.has_partial:
+                continue
+            # subgroups copied across (hdim Duplicate) each cover the
+            # whole tensor, however they split it: count the first alone
+            seen = set()
+            for dev in (annot.dgs[0].devices if annot.hdim == DUP
+                        else annot.devices):
+                box = annot.device_box(dev, shape)
+                if box not in seen:
+                    seen.add(box)
+                    rows.append(order.pos(dev))
+                    cols.append(i)
+            local = {dev: tuple(annot.device_shape(dev, shape))
+                     for dev in annot.devices}
+            if any(s != self.pads[name] for s in local.values()):
+                b = np.zeros((n_mesh, len(shape)), np.int32)
+                for dev, s in local.items():
+                    b[order.pos(dev)] = s
+                self.bounds[i] = b
+        self.rows = np.array(rows, np.int32)
+        self.cols = np.array(cols, np.int32)
+        self._zeros = None
+
+    def pack(self, sts: dict) -> list[np.ndarray]:
+        """Host blocks of ``{name: ShardedTensor}``, in ``names`` order."""
+        from repro.runtime.lowering import pack_shards
+
+        return [pack_shards(sts[n].parts, self.annots[n], self.shapes[n],
+                            self.n_mesh, self.order) for n in self.names]
+
+    def unpack(self, name: str, rows) -> "ShardedTensor":
+        """A ShardedTensor of views into ``rows`` (row ``p``: mesh
+        position ``p``'s padded shard)."""
+        from repro.core.simulator import ShardedTensor
+
+        annot, shape = self.annots[name], self.shapes[name]
+        return ShardedTensor(shape, annot, {
+            dev: rows[self.order.pos(dev)][
+                tuple(slice(0, s) for s in annot.device_shape(dev, shape))]
+            for dev in annot.devices})
+
+    def _sharding(self):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        return NamedSharding(self.mesh, P(self.mesh.axis_names[0]))
+
+    def put(self, sts: dict) -> "Blocks":
+        """Place ``{name: ShardedTensor}`` host shards on the mesh."""
+        return Blocks(self, zip(self.names, jax.device_put(
+            self.pack(sts), self._sharding())))
+
+    def zeros(self) -> "Blocks":
+        """float32 zero blocks made on the mesh (one program, compiled
+        on first use)."""
+        if self._zeros is None:
+            shapes = [(self.n_mesh,) + self.pads[n] for n in self.names]
+
+            def _zero_blocks():
+                return tuple(jnp.zeros(s, jnp.float32) for s in shapes)
+
+            self._zeros = jax.jit(_zero_blocks,
+                                  out_shardings=self._sharding())
+        return Blocks(self, zip(self.names, self._zeros()))
+
+
+class Blocks(dict):
+    """``{name: stacked (n_mesh, *pad) array}`` on a device mesh, placed
+    as ``layout`` says."""
+
+    def __init__(self, layout: BlockLayout, arrays=()):
+        super().__init__(arrays)
+        self.layout = layout
+
+    def host(self) -> dict:
+        """``{name: ShardedTensor}`` host views of the blocks."""
+        from repro.runtime.program import fetch_rows
+
+        names = list(self)
+        rows = fetch_rows([self[n] for n in names], self.layout.n_mesh)
+        return {n: self.layout.unpack(n, r) for n, r in zip(names, rows)}
+
+
+def _row_squares(gs, bounds):
+    """Per leaf, the sum of the row's squared gradient; padding (outside
+    ``bounds``, where a leaf's shards differ in shape) counts zero."""
+    out = []
+    for i, g in enumerate(gs):
+        g = g.astype(jnp.float32)
+        b = bounds.get(i)
+        if b is not None:
+            inside = True
+            for d in range(1, g.ndim):
+                inside = inside & (jax.lax.broadcasted_iota(
+                    jnp.int32, g.shape, d) < b[0, d - 1])
+            g = jnp.where(inside, g, 0.0)
+        out.append(jnp.sum(g * g))
+    return jnp.stack(out)[None]
+
+
+#: order of the scalars :func:`_row_update` takes in ``hyper``
+HYPER = ("lr", "bc1", "bc2", "b1", "omb1", "b2", "omb2", "eps", "wd",
+         "clip", "extra")
+
+
+def _row_update(hyper, sq, rows, cols, ps, gs, ms, vs):
+    """The global gradient norm from every row's ``sq`` (one entry per
+    tile, plus ``extra``, the squares of leaves without tiles), the clip
+    scale, and :func:`adamw_leaf` on each of the row's leaves."""
+    h = dict(zip(HYPER, (hyper[i] for i in range(len(HYPER)))))
+    gnorm = jnp.sqrt(jnp.sum(sq[rows, cols]) + h["extra"])
+    scale = jnp.minimum(jnp.float32(1),
+                        h["clip"] / (gnorm + jnp.float32(1e-9)))
+    out = [adamw_leaf(p, g, m, v, scale, h["lr"], h["bc1"], h["bc2"],
+                      h["b1"], h["omb1"], h["b2"], h["omb2"], h["eps"],
+                      h["wd"])
+           for p, g, m, v in zip(ps, gs, ms, vs)]
+    return (tuple(o[0] for o in out), tuple(o[1] for o in out),
+            tuple(o[2] for o in out), gnorm)
+
+
+@functools.lru_cache(maxsize=16)
+def _programs(mesh):
+    """The two jitted programs over ``mesh``'s rows; the update donates
+    m and v."""
+    from jax.sharding import PartitionSpec as P
+
+    row, whole = P(mesh.axis_names[0]), P()
+    squares = jax.jit(jax.shard_map(
+        _row_squares, mesh=mesh, in_specs=(row, row), out_specs=row,
+        check_vma=False))
+    update = jax.jit(jax.shard_map(
+        _row_update, mesh=mesh,
+        in_specs=(whole, whole, whole, whole, row, row, row, row),
+        out_specs=(row, row, row, whole), check_vma=False),
+        donate_argnums=(6, 7))
+    return squares, update
+
+
+@functools.lru_cache(maxsize=1)
+def _cpu_mesh():
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(jax.devices("cpu")[:1]), ("rows",))
+
+
+def _hyper(cfg: AdamWConfig, count: int) -> np.ndarray:
+    c = np.float32(count)
+    b1, b2 = np.float32(cfg.b1), np.float32(cfg.b2)
+    warm = min(float(count) / max(cfg.warmup_steps, 1), 1.0)
+    vals = {"lr": cfg.lr * warm, "bc1": 1 - b1 ** c, "bc2": 1 - b2 ** c,
+            "b1": b1, "omb1": 1 - cfg.b1, "b2": b2, "omb2": 1 - cfg.b2,
+            "eps": cfg.eps, "wd": cfg.weight_decay, "clip": cfg.grad_clip,
+            "extra": 0.0}
+    return np.array([vals[k] for k in HYPER], np.float32)
+
+
+def _host_layout(params: dict) -> BlockLayout:
+    from repro.runtime.lowering import DeviceOrder
+
+    order = DeviceOrder(tuple(sorted(
+        {d for st in params.values() for d in st.annot.devices})))
+    items = tuple((n, params[n].annot, tuple(params[n].shape))
+                  for n in sorted(params))
+    return block_layout(order, len(order), None, items)
+
 
 def init_sharded_state(params):
-    """Optimizer state mirroring a ``{name: ShardedTensor}`` weight dict
-    (fp32 m/v shards under the SAME annotations — ZeRO-3 storage when
-    the params are sharded, ZeRO-1 when only the states are)."""
-    import numpy as np
+    """Zero optimizer state mirroring ``params``: float32 m/v shards
+    under the SAME annotations (ZeRO-3 storage when the params are
+    sharded, ZeRO-1 when only the states are), as host shards for
+    ``{name: ShardedTensor}`` and as blocks on the mesh for
+    :class:`Blocks`."""
+    if isinstance(params, Blocks):
+        lay = params.layout
+        return {"m": lay.zeros(), "v": lay.zeros(), "count": 0}
 
     from repro.core.simulator import ShardedTensor
 
@@ -107,247 +331,74 @@ def init_sharded_state(params):
             "count": 0}
 
 
-_TILE_GROUP_CACHE: dict = {}
-
-
-def _tile_groups(st):
-    """Group a ShardedTensor's devices by the global tile their shard
-    covers: one entry per distinct tile, listing the tile's replicas.
-    Returns ``None`` when the shards are not plain tiles (any Partial
-    layout — shards are then summands, not copies), so callers fall
-    back to per-device handling.  Pure geometry — memoized on the
-    (annotation, shape) pair, which the optimizer revisits every step."""
-    from repro.core.annotations import DUP, PARTIAL
-
-    annot = st.annot
-    ck = (annot, st.shape)
-    hit = _TILE_GROUP_CACHE.get(ck, False)
-    if hit is not False:
-        return hit
-    out = None
-    if annot.hdim != PARTIAL:
-        groups: dict[tuple, list[int]] = {}
-        for g, (dg, ds) in enumerate(zip(annot.dgs, annot.dss)):
-            if ds.has_partial:
-                groups = None
-                break
-            slab = annot.subgroup_shape(g, st.shape)
-            key_g = 0 if annot.hdim == DUP else g
-            for pos, dev in enumerate(dg):
-                box = ds.local_box(pos, slab)
-                groups.setdefault((key_g, box), []).append(dev)
-        if groups is not None:
-            out = list(groups.values())
-    _TILE_GROUP_CACHE[ck] = out
-    return out
-
-
-def sharded_grad_norm(grads) -> float:
-    """Global gradient norm over ``{name: ShardedTensor}`` — replicas
-    counted once, fp32 accumulation like :func:`apply_updates`.
-
-    Computed tile-by-tile from the shards in hand (split dims tile the
-    global value, so the squared norm decomposes exactly); only Partial
-    layouts — where shards are summands — reconstruct via ``gather``."""
-    import numpy as np
-
-    from repro.core.simulator import gather
-
-    acc = np.float32(0.0)
-    for st in grads.values():
-        tiles = _tile_groups(st)
-        if tiles is None:
-            g = np.asarray(gather(st, check_dups=False), np.float32)
-            acc = acc + np.sum(np.square(g), dtype=np.float32)
-        else:
-            for devs in tiles:
-                g = np.asarray(st.parts[devs[0]], np.float32)
-                acc = acc + np.sum(np.square(g), dtype=np.float32)
-    return float(np.sqrt(acc))
-
-
 def sharded_apply_updates(params, grads, opt_state, cfg: AdamWConfig):
-    """AdamW over sharded weights: returns ``(new_params, new_state,
-    metrics)`` with the same structure; deterministic numpy, identical
-    for both executors given identical gradient shards."""
-    import numpy as np
+    """AdamW over sharded weights: ``(new_params, new_state, metrics)``,
+    each in the form it was given.
 
-    from repro.core.simulator import ShardedTensor
+    ``params`` and ``grads`` are either host shards,
+    ``{name: ShardedTensor}`` with ``opt_state`` as
+    :func:`init_sharded_state` makes it, or :class:`Blocks` on one mesh
+    with m and v Blocks in ``opt_state``.  Blocks stay on the device and
+    the update has finished on return; the given m and v blocks are
+    donated to the new ones, ``params`` stay valid.  The step's
+    telemetry record counts the leaves updated on the device and on the
+    host, and gauges the optimizer state the device holds."""
+    from repro.runtime import telemetry
 
     if set(params) != set(grads):
         raise ValueError(
             f"gradient names {sorted(grads)} do not match parameters "
             f"{sorted(params)}")
     count = opt_state["count"] + 1
-    c = np.float32(count)
-    bc1 = np.float32(1) - np.float32(cfg.b1) ** c
-    bc2 = np.float32(1) - np.float32(cfg.b2) ** c
-    warm = min(float(count) / max(cfg.warmup_steps, 1), 1.0)
-    lr = np.float32(cfg.lr * warm)
+    hyper = _hyper(cfg, count)
+    if isinstance(params, Blocks):
+        lay = params.layout
+        squares, update = _programs(lay.mesh)
+        gs = tuple(grads[n] for n in lay.names)
+        new = update(hyper, squares(gs, lay.bounds), lay.rows,
+                     lay.cols, tuple(params[n] for n in lay.names), gs,
+                     tuple(opt_state["m"][n] for n in lay.names),
+                     tuple(opt_state["v"][n] for n in lay.names))
+        new_p, new_m, new_v = (Blocks(lay, zip(lay.names, x))
+                               for x in jax.block_until_ready(new[:3]))
+        gnorm = float(new[3])
+        telemetry.count("opt_device_leaves", len(lay.names))
+        telemetry.gauge("device_state_bytes", sum(
+            a.nbytes for a in (*new_m.values(), *new_v.values())))
+    else:
+        new_p, new_m, new_v, gnorm = _host_update(params, grads, opt_state,
+                                                  hyper)
+        telemetry.count("opt_host_leaves", len(params))
+        telemetry.gauge("device_state_bytes", 0)
+    metrics = {"grad_norm": gnorm, "lr": float(hyper[HYPER.index("lr")])}
+    return new_p, {"m": new_m, "v": new_v, "count": count}, metrics
 
-    b1, omb1 = np.float32(cfg.b1), np.float32(1 - cfg.b1)
-    b2, omb2 = np.float32(cfg.b2), np.float32(1 - cfg.b2)
-    eps, wd = np.float32(cfg.eps), np.float32(cfg.weight_decay)
 
-    def upd(arr, g_arr, m_prev, v_prev):
-        g = np.asarray(g_arr, np.float32) * scale
-        m_ = b1 * m_prev + omb1 * g
-        v_ = b2 * v_prev + omb2 * g * g
-        step = (m_ / bc1) / (np.sqrt(v_ / bc2) + eps)
-        step = step + wd * arr.astype(np.float32)
-        return (arr.astype(np.float32) - lr * step).astype(arr.dtype), \
-            m_, v_
+def _host_update(params, grads, opt_state, hyper):
+    """The two programs row by row on the CPU over host shards."""
+    from repro.core.simulator import gather
 
-    # replicas of a tile receive bit-identical updates (identical numpy
-    # arithmetic on identical inputs), so each tile is computed once and
-    # its result arrays shared across the replica devices — the same
-    # class-dedup the lowered executors apply to compute.  fp32 tiles
-    # additionally batch into ONE flat buffer so the ~16-op elementwise
-    # chain dispatches once per STEP instead of once per tile (the tiles
-    # are small enough that numpy per-call overhead, not bandwidth,
-    # dominates).  Per-element operation order is identical to ``upd``,
-    # so the batched path is bit-for-bit the per-tile path.
-    new_params: dict[str, object] = {}
-    new_m: dict[str, object] = {}
-    new_v: dict[str, object] = {}
-    pp_all = {name: {} for name in params}
-    mm_all = {name: {} for name in params}
-    vv_all = {name: {} for name in params}
-    jobs: list[tuple] = []      # (name, devs, p, g, m, v) fp32 tiles
-    fb_tiles: list[tuple] = []  # deduped tiles on the per-tile path
-    fb_names: list[str] = []    # tensors updated per device (Partial)
-    for name, p in params.items():
-        g_st, m_st = grads[name], opt_state["m"][name]
-        v_st = opt_state["v"][name]
-        tiles = _tile_groups(p)
-        if tiles is not None and all(
-                devs[0] in g_st.parts and devs[0] in m_st.parts
-                and p.parts[devs[0]].dtype == np.float32
-                and g_st.parts[devs[0]].dtype == np.float32
-                for devs in tiles):
-            for devs in tiles:
-                d0 = devs[0]
-                jobs.append((name, devs, p.parts[d0], g_st.parts[d0],
-                             m_st.parts[d0], v_st.parts[d0]))
-        elif tiles is not None and all(
-                devs[0] in g_st.parts and devs[0] in m_st.parts
-                for devs in tiles):
-            for devs in tiles:
-                d0 = devs[0]
-                fb_tiles.append((name, devs, p.parts[d0],
-                                 g_st.parts[d0], m_st.parts[d0],
-                                 v_st.parts[d0]))
-        else:                   # Partial shards: per-device update
-            fb_names.append(name)
-    # steady-state reuse: the views handed out below are contiguous
-    # slices of the flat buffers IN JOB ORDER, so when the caller feeds
-    # the previous step's params/state straight back (the training
-    # loop), the flat P/M/V buffers already hold this step's inputs and
-    # the update runs fully in place — no 3x whole-model concatenate.
-    # Validated by base identity + byte offset per tile; any reshard,
-    # switch() migration or fresh state fails the check and falls back
-    # to the concat path.  In-place means the PREVIOUS step's param/
-    # state views alias the updated values afterwards — the optimizer
-    # consumes its inputs, like any in-place optimizer.
-    prev = opt_state.get("_flat")
-    flat_cache = None
-    if jobs:
-        layout = tuple((j[0], tuple(j[1]), j[2].size) for j in jobs)
-        reuse = prev is not None and prev["layout"] == layout
-        if reuse:
-            Pb, Mb, Vb = prev["P"], prev["M"], prev["V"]
-            pa = Pb.__array_interface__["data"][0]
-            ma = Mb.__array_interface__["data"][0]
-            va = Vb.__array_interface__["data"][0]
-            off = 0
-            for _, _, p0, _, m0, v0 in jobs:
-                want = off * 4
-                if not (p0.base is Pb and m0.base is Mb
-                        and v0.base is Vb
-                        and p0.__array_interface__["data"][0] - pa == want
-                        and m0.__array_interface__["data"][0] - ma == want
-                        and v0.__array_interface__["data"][0] - va == want):
-                    reuse = False
-                    break
-                off += p0.size
-        if reuse:
-            P, M, V = prev["P"], prev["M"], prev["V"]
-            G, t, S = prev["G"], prev["t"], prev["S"]
-        else:
-            P, M, V = (np.concatenate([j[i].ravel() for j in jobs])
-                       for i in (2, 4, 5))
-            G = np.empty_like(P)
-            t = np.empty_like(P)
-            S = np.empty_like(P)
-        off = 0                 # grads land in G in ONE pass per tile
-        for _, _, _, g0, _, _ in jobs:
-            n = g0.size
-            np.copyto(G[off:off + n].reshape(g0.shape), g0)
-            off += n
-        flat_cache = {"layout": layout, "P": P, "M": M, "V": V,
-                      "G": G, "t": t, "S": S}
+    lay = _host_layout(params)
+    ps, gs, ms, vs = (lay.pack(x) for x in
+                      (params, grads, opt_state["m"], opt_state["v"]))
+    hyper = hyper.copy()
+    for n in lay.partial:       # summands: square the value they sum to
+        g = np.asarray(gather(grads[n], check_dups=False), np.float32)
+        hyper[HYPER.index("extra")] += np.sum(np.square(g), dtype=np.float32)
+    cpu = jax.devices("cpu")[0]
+    squares, update = _programs(_cpu_mesh())
 
-    # global grad norm: one BLAS dot over the flat buffer; tensors off
-    # the flat path contribute through the tile/gather logic of
-    # :func:`sharded_grad_norm`.  fp32 accumulation either way.
-    sq = np.float32(np.dot(G, G)) if jobs else np.float32(0.0)
-    fb_norm = {j[0] for j in fb_tiles} | set(fb_names)
-    if fb_norm:
-        sq = sq + np.float32(
-            sharded_grad_norm({n: grads[n] for n in fb_norm})) ** 2
-    gnorm = np.sqrt(sq)
-    scale = np.minimum(np.float32(1.0),
-                       np.float32(cfg.grad_clip) / (gnorm + np.float32(1e-9)))
+    def row(blocks, r):
+        return tuple(jax.device_put([b[r:r + 1] for b in blocks], cpu))
 
-    for name, devs, p0, g0, m0, v0 in fb_tiles:
-        p_, m_, v_ = upd(p0, g0, m0, v0)
-        for dev in devs:
-            pp_all[name][dev] = p_
-            mm_all[name][dev] = m_
-            vv_all[name][dev] = v_
-    for name in fb_names:
-        p, g_st = params[name], grads[name]
-        m_st, v_st = opt_state["m"][name], opt_state["v"][name]
-        for dev, arr in p.parts.items():
-            pp_all[name][dev], mm_all[name][dev], vv_all[name][dev] = \
-                upd(arr, g_st.parts[dev], m_st.parts[dev],
-                    v_st.parts[dev])
-
-    if jobs:
-        G *= scale                              # g = g * scale
-        M *= b1                                 # m = b1*m + omb1*g
-        np.multiply(G, omb1, out=t)
-        M += t
-        V *= b2                                 # v = b2*v + (omb2*g)*g
-        np.multiply(G, omb2, out=t)
-        t *= G
-        V += t
-        np.divide(M, bc1, out=S)                # (m/bc1)/(sqrt(v/bc2)+eps)
-        np.divide(V, bc2, out=t)
-        np.sqrt(t, out=t)
-        t += eps
-        S /= t
-        np.multiply(P, wd, out=t)               # step += wd*p
-        S += t
-        S *= lr                                 # p -= lr*step
-        P -= S
-        off = 0
-        for name, devs, p0, _, _, _ in jobs:
-            n = p0.size
-            p_ = P[off:off + n].reshape(p0.shape)
-            m_ = M[off:off + n].reshape(p0.shape)
-            v_ = V[off:off + n].reshape(p0.shape)
-            off += n
-            for dev in devs:
-                pp_all[name][dev] = p_
-                mm_all[name][dev] = m_
-                vv_all[name][dev] = v_
-    for name, p in params.items():
-        new_params[name] = ShardedTensor(p.shape, p.annot, pp_all[name])
-        new_m[name] = ShardedTensor(p.shape, p.annot, mm_all[name])
-        new_v[name] = ShardedTensor(p.shape, p.annot, vv_all[name])
-    metrics = {"grad_norm": float(gnorm), "lr": float(lr)}
-    new_state = {"m": new_m, "v": new_v, "count": count}
-    if flat_cache is not None:
-        new_state["_flat"] = flat_cache
-    return new_params, new_state, metrics
+    sq = np.concatenate([np.asarray(squares(
+        row(gs, r), {i: jax.device_put(b[r:r + 1], cpu)
+                     for i, b in lay.bounds.items()}))
+        for r in range(lay.n_mesh)])
+    head = jax.device_put((hyper, sq, lay.rows, lay.cols), cpu)
+    outs = [jax.device_get(update(*head, row(ps, r), row(gs, r), row(ms, r),
+                                  row(vs, r)))
+            for r in range(lay.n_mesh)]
+    new = tuple({n: lay.unpack(n, [o[k][i][0] for o in outs])
+                 for i, n in enumerate(lay.names)} for k in range(3))
+    return new + (float(outs[0][3]),)

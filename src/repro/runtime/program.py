@@ -592,10 +592,16 @@ class LoweredGraph:
     def _fetch_rows(self, outs) -> list:
         return fetch_rows(outs, self.n_mesh)
 
-    def run(self, state: dict[str, ShardedTensor]
+    def run(self, state: dict[str, ShardedTensor], update=None
             ) -> dict[str, ShardedTensor]:
         """Execute once; ``state`` maps every leaf name (placeholder AND
-        parameter) to its ShardedTensor under the strategy annotation."""
+        parameter) to its ShardedTensor under the strategy annotation.
+
+        ``update``, when given, runs between the call and the fetch as
+        ``update(placed, outs)``: both map names to device arrays, the
+        placed leaf blocks and the step's outputs, and it returns the
+        ``{name: array}`` to fetch instead of ``outs`` (each unpacked
+        under its name's annotation)."""
         if self.num_microbatches != 1:
             raise ValueError("microbatched program: use run_microbatches")
         blocks = []
@@ -610,7 +616,7 @@ class LoweredGraph:
             telemetry.hold(self._pack_bufs)
         return self._run_blocks(blocks, lambda fetched: {
             name: self._unpack(name, rows)
-            for name, rows in zip(self.fetches, fetched)})
+            for name, rows in fetched.items()}, update)
 
     def run_microbatches(self, states: list[dict[str, ShardedTensor]]
                          ) -> list[dict[str, ShardedTensor]]:
@@ -649,7 +655,7 @@ class LoweredGraph:
         def unpack(fetched):
             results: list[dict[str, ShardedTensor]] = \
                 [{} for _ in range(m)]
-            for name, rows in zip(self.fetches, fetched):
+            for name, rows in fetched.items():
                 for j in range(m):              # rows[pos] (m, *pad)
                     results[j][name] = self._unpack(
                         name, [r[j] for r in rows])
@@ -657,9 +663,10 @@ class LoweredGraph:
 
         return self._run_blocks(blocks, unpack)
 
-    def _run_blocks(self, blocks: list[np.ndarray], unpack):
+    def _run_blocks(self, blocks: list[np.ndarray], unpack, update=None):
         """Place the packed leaf blocks, run the step program until its
-        outputs are ready, and hand their host rows to ``unpack``."""
+        outputs are ready, let ``update`` (see :meth:`run`) replace
+        them, and hand the fetched host rows, by name, to ``unpack``."""
         import jax
 
         with telemetry.span("feed.put"):
@@ -668,16 +675,21 @@ class LoweredGraph:
         with telemetry.span("call"):
             # the fetch would wait for the outputs anyway; waiting here
             # keeps that wait in the call's span and out of the fetch's
-            outs = jax.block_until_ready(self.fn(*placed))
+            outs = dict(zip(self.fetches,
+                            jax.block_until_ready(self.fn(*placed))))
+        if update is not None:
+            outs = update({t.name: b for t, b in zip(self.leaves, placed)},
+                          outs)
         # drop the step's device arrays as soon as they are used: held
         # to the end of the step they moved ~60 ms of host work a step
         # (1.68 GB each way, TPU v5e) from the next put to between steps
         del placed
-        telemetry.count("d2h_bytes", sum(o.nbytes for o in outs))
+        telemetry.count("d2h_bytes", sum(o.nbytes for o in outs.values()))
         with telemetry.span("fetch"):
-            rows = self._fetch_rows(outs)
+            rows = self._fetch_rows(list(outs.values()))
+            fetched = dict(zip(outs, rows))
             del outs
-            return unpack(rows)
+            return unpack(fetched)
 
 
 def plan_input_name(graph: Graph, op_id: int) -> str:

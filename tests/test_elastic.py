@@ -203,31 +203,24 @@ def test_fault_validation():
         inject([(0, (0,))], FaultPlan((Fault(1, "kill", (0,)),)), 3)
 
 
-# -- flat-buffer AdamW: switches trip the fallback, never corrupt -----------
+# -- a switch then a restore: m/v moved, then assigned as host shards -------
 
-def test_switch_trips_flat_adamw_fallback():
-    """PR 8's in-place flat-buffer AdamW validates layout + buffer
-    identity; a strategy switch migrates m/v to fresh arrays, so the
-    next step must REBUILD the flat buffer (fallback), not crash or
-    reuse stale views — and stay bitwise on the reference."""
-    from repro import api
-    program = api.Program(probe_graph(), [REF_STRATEGY])
-    session = api.Session(program, 0)
-    session.load(probe_values())
-    session.train_step(probe_feeds(0))
-    session.train_step(probe_feeds(1))
-    f1 = session.opt_state["_flat"]["P"]
-    session.train_step(probe_feeds(2))
-    assert session.opt_state["_flat"]["P"] is f1  # steady-state reuse
-    session.switch(probe_layout([0, 1], "dp"))
-    assert session.opt_state.get("_flat") is not None  # stale cache kept
-    session.train_step(probe_feeds(3))
-    f2 = session.opt_state["_flat"]["P"]
-    assert f2 is not f1                            # fallback rebuilt it
-    ref, _ = reference_run(REF_STRATEGY, 4)
-    want, got = snap(ref), snap(session)
-    for key in want:
-        np.testing.assert_array_equal(got[key], want[key], err_msg=key)
+def test_switch_then_restore_stays_bitwise(tmp_path):
+    """A switch migrates m/v onto new shards, and an elastic restore
+    assigns host shards to ``Session.opt_state``: a run through both
+    (shrink at step 2, checkpoint and crash at 4, resume on other
+    devices) stays bitwise on the uninterrupted reference."""
+    n_steps = 6
+    faults = FaultPlan((Fault(4, "crash", phase="post-checkpoint"),))
+    driver = make_driver(checkpoint_every=2, ckpt_dir=str(tmp_path),
+                         faults=faults)
+    trace = [(0, (0, 1, 2, 3), "dp"), (2, (0, 1), "dp")]
+    run = driver.run(trace, n_steps)
+    assert run.interrupted_at == 4
+    assert run.transition_kinds() == ["shrink"]
+    run2 = driver.resume(trace, n_steps, ranks=(2, 3), layout="pp")
+    assert [s.step for s in run2.steps] == [4, 5]
+    assert_matches_reference(driver, run.losses + run2.losses, n_steps)
 
 
 # -- checkpoint atomicity (satellite regression) -----------------------------
