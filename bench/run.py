@@ -9,7 +9,13 @@ a traffic mix or a metric as new files plus entries in
 ``BENCHMARK.json``, and edits none:
 
 - ``bench/configs/<config>.json``: the model's sizes as run, its source,
-  the keys cut from it (``reduced``), what was ``assumed``, the precision;
+  the keys cut from it (``reduced``), what was ``assumed``, the precision,
+  and its architecture (``arch``);
+- ``bench/archs/<arch>.py``: what depends on the architecture: the
+  program as the system builds it, the parameters in the program's
+  order, the plain float32 loss, the training work per token, the
+  flash-attention calls of a step and the CPU-size twin (see
+  ``bench/archs/dense.py``);
 - ``bench/traffic/<traffic>.json``: the job (batch, sequence length,
   optimizer settings); ids and labels are drawn from ``(seed, step)``;
 - ``bench/workloads/<cell>.json``: the layout (``dp``, ``tp``) and the
@@ -17,8 +23,8 @@ a traffic mix or a metric as new files plus entries in
 - ``bench/metrics/<metric>.py``: ``read(run) -> float | None`` for one
   metric (``run`` is a :class:`Run`); ``None`` leaves it out of the line.
 
-The run drives the system as a user would: ``models.graph_block.
-block_program`` -> ``Program.compile_train`` -> ``Session.train_step`` on
+The run drives the system as a user would: the architecture's
+``program`` -> ``Program.compile_train`` -> ``Session.train_step`` on
 ``api.JaxExecutor`` over a mesh of the cell's chips.  Set-up makes the
 weights from the seed on the device in one jitted call and loads them,
 compiles the step ahead of time, and runs the first three steps, which
@@ -97,9 +103,13 @@ class Run:
     memory_peak_bytes: int | None = None
 
     @property
+    def arch(self):
+        return self.cell["arch"]
+
+    @property
     def flops_per_step(self) -> float:
-        from bench import flops
-        return flops.train_flops_per_token(self.model, self.traffic["seq"]) \
+        return self.arch.train_flops_per_token(
+            self.model, self.traffic["seq"]) \
             * self.traffic["batch"] * self.traffic["seq"]
 
 
@@ -108,10 +118,31 @@ def read_json(path: str) -> dict:
         return json.load(f)
 
 
+def load_file(path: str, prefix: str):
+    """The Python file at ``path`` as a module named ``prefix`` plus
+    its base name."""
+    base = os.path.basename(path)[:-len(".py")]
+    spec = importlib.util.spec_from_file_location(
+        prefix + base.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_arch(name: str, root: str = ROOT):
+    """The architecture module ``bench/archs/<name>.py`` under
+    ``root``."""
+    path = os.path.join(root, "bench", "archs", name + ".py")
+    if not os.path.isfile(path):
+        raise BenchError(f"no architecture module {path}")
+    return load_file(path, "bench_arch_")
+
+
 def load_cell(name: str, root: str = ROOT) -> dict:
     """The ``BENCHMARK.json`` entry of cell ``name`` with its files:
-    ``model``, ``traffic_mix``, ``layout``, ``limits`` and the metrics
-    it reports (``end_to_end``, ``per_layer``: ``[(name, unit)]``)."""
+    ``model``, its architecture module ``arch``, ``traffic_mix``,
+    ``layout``, ``limits`` and the metrics it reports (``end_to_end``,
+    ``per_layer``: ``[(name, unit)]``)."""
     spec = read_json(os.path.join(root, "BENCHMARK.json"))
     entry = next((w for w in spec["workloads"] if w["name"] == name),
                  None)
@@ -121,6 +152,7 @@ def load_cell(name: str, root: str = ROOT) -> dict:
     cell = dict(entry)
     cell["model"] = read_json(os.path.join(bench, "configs",
                                            entry["config"] + ".json"))
+    cell["arch"] = load_arch(cell["model"]["arch"], root)
     cell["traffic_mix"] = read_json(os.path.join(bench, "traffic",
                                                  entry["traffic"] + ".json"))
     cell.update(read_json(os.path.join(bench, "workloads", name + ".json")))
@@ -128,21 +160,6 @@ def load_cell(name: str, root: str = ROOT) -> dict:
         cell[kind] = [(m["name"], m["unit"]) for m in spec[kind]
                       if name in m.get("workloads", [name])]
     return cell
-
-
-def program_config(m: dict):
-    """The program's ``ModelConfig`` for configuration ``m``."""
-    from repro.models.config import ModelConfig
-
-    if m["hidden_act"] != "silu":
-        raise BenchError(f"{m['name']}: only SwiGLU blocks are built")
-    return ModelConfig(
-        name=m["name"], family="dense", n_layers=m["num_hidden_layers"],
-        d_model=m["hidden_size"], n_heads=m["num_attention_heads"],
-        n_kv_heads=m["num_key_value_heads"], d_ff=m["intermediate_size"],
-        vocab=m["vocab_size"], head_dim=m["head_dim"],
-        qkv_bias=m["qkv_bias"], mlp="swiglu", norm_eps=m["rms_norm_eps"],
-        tie_embeddings=m["tie_word_embeddings"], source=m["source"])
 
 
 def feeds(seed: int, step: int, batch: int, seq: int, vocab: int) -> dict:
@@ -356,21 +373,22 @@ def log_window_noise(t0, marks, ticks0, use0, gcw, log) -> None:
         f"{100 * steal / max(total, 1):.2f}%")
 
 
-def reference_record(m: dict, opt: dict, seed: int, batch: int, seq: int,
-                     precision: str = "highest",
+def reference_record(arch, m: dict, opt: dict, seed: int, batch: int,
+                     seq: int, precision: str = "highest",
                      log=lambda *_: None) -> dict:
-    """The reference's record of the first ``FIRST_STEPS`` steps, on
-    the default device at ``precision``.  Weights and gradients stay on
-    the device; AdamW runs there leaf by leaf, with the moments kept on
-    the host between steps (at 14B widths they would not fit beside the
-    gradients on one chip)."""
+    """The reference's record of the first ``FIRST_STEPS`` steps of
+    model ``m`` of architecture ``arch``, on the default device at
+    ``precision``.  Weights and gradients stay on the device; AdamW runs
+    there leaf by leaf, with the moments kept on the host between steps
+    (at 14B widths they would not fit beside the gradients on one
+    chip)."""
     import jax
     import jax.numpy as jnp
 
     from bench import check, reference
 
-    make = reference.weight_maker(m)
-    loss_and_grad = reference.loss_and_grad(m)
+    make = reference.weight_maker(arch, m)
+    loss_and_grad = reference.loss_and_grad(arch, m)
     clip, update = reference.adamw(opt)
     lo, hi = reference.seed_words(seed)
     params = make(lo, hi)
@@ -412,12 +430,8 @@ def read_metrics(names, run: Run) -> dict:
     that finds nothing to read is left out."""
     out = {}
     for name, unit in names:
-        path = os.path.join(BENCH, "metrics", name + ".py")
-        spec = importlib.util.spec_from_file_location(
-            "bench_metric_" + name.replace(".", "_").replace("-", "_"),
-            path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
+        mod = load_file(os.path.join(BENCH, "metrics", name + ".py"),
+                        "bench_metric_")
         value = mod.read(run)
         if value is not None:
             out[name] = {"value": float(value), "unit": unit}
@@ -460,7 +474,6 @@ class Trainer:
         import jax
         from jax.sharding import Mesh
         from repro import api
-        from repro.models.graph_block import block_program
         from repro.optim.adamw import AdamWConfig
 
         from bench import reference
@@ -468,14 +481,11 @@ class Trainer:
         self.cell, self.spans = cell, spans
         self.m, self.tr = cell["model"], cell["traffic_mix"]
         self.precision = self.m["precision"]["matmul"]
-        self.make = reference.weight_maker(self.m)
+        self.make = reference.weight_maker(cell["arch"], self.m)
         with jax.default_matmul_precision(self.precision):
             with spans.span("plan"):
-                prog = block_program(
-                    program_config(self.m), batch=self.tr["batch"],
-                    seq=self.tr["seq"],
-                    n_layers=self.m["num_hidden_layers"],
-                    dp=cell["layout"]["dp"], tp=cell["layout"]["tp"], pp=1)
+                prog = cell["arch"].program(self.m, self.tr["batch"],
+                                            self.tr["seq"], cell["layout"])
                 ex = api.JaxExecutor(mesh=Mesh(np.array(devs), ("dev",)))
                 self.sess = api.Session(
                     prog, 0, executor=ex,
@@ -547,8 +557,8 @@ def compare(cell: dict, seed: int, prog: dict, log=print):
 
     m, tr = cell["model"], cell["traffic_mix"]
     t0 = time.perf_counter()
-    ref = reference_record(m, tr["optimizer"], seed, tr["batch"], tr["seq"],
-                           log=log)
+    ref = reference_record(cell["arch"], m, tr["optimizer"], seed,
+                           tr["batch"], tr["seq"], log=log)
     log(f"reference: {time.perf_counter() - t0:.1f} s; host {host_memory()}")
     nums = check.numbers(prog, ref)
     ok, _ = check.verdict(nums, cell["limits"])
@@ -569,7 +579,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool = False, *,
             chips=cell["chips"],
             peaks=device_peaks(kind) if devs[0].platform == "tpu" else None)
     log(f"device: {kind} ({devs[0].platform}), {len(devs)} chip(s) used "
-        f"of {len(jax.devices())}; compile cache {compile_cache()}")
+        f"of {len(jax.devices())}; compile cache {compile_cache()}; "
+        f"training FLOPs per step {r.flops_per_step!r}")
     spans = Spans()
     trainer = Trainer(cell, devs, spans, log)
     with instrumented(spans, trainer.lw):

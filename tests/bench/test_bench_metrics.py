@@ -34,3 +34,59 @@ def test_busy_mfu_is_the_step_work_over_busy_time():
 @pytest.mark.parametrize("busy_s", [None, 0.0])
 def test_busy_mfu_is_silent_without_busy_time(busy_s):
     assert run.read_metrics([("busy_mfu", "%")], _run(busy_s=busy_s)) == {}
+
+
+def _traced(calls=176.0, secs=0.4966):
+    r = _run()
+    r.trace["ops"] = {"flash_attention.1": (secs / 2, calls / 2),
+                      "flash_attention.2": (secs / 2, calls / 2),
+                      "fusion.3": (9.8, 1000.0)}
+    return r
+
+
+def test_flash_attn_roofline_of_a_dense_step():
+    """Every call of the dense step is the same: its least time, by the
+    calls in the trace, over their device time."""
+    from bench import flops
+
+    r = _traced()
+    ops, nbytes = flops.flash_attention(4, 12, 2, 1024, 128)
+    least = max(ops / 197e12, nbytes / 819e9)
+    got = run.read_metrics([("flash_attn_roofline", "%")], r)
+    assert got["flash_attn_roofline"]["value"] == \
+        100.0 * least * 176.0 / 0.4966
+
+
+class _Windowed:
+    """An architecture whose step makes one full and three windowed
+    calls."""
+
+    @staticmethod
+    def attention_calls(m, traffic, layout):
+        from bench import flops
+
+        full = flops.flash_attention(1, 32, 4, 4096, 128)
+        near = flops.flash_attention(1, 32, 4, 4096, 128, window=1024)
+        return [near, near, near, full]
+
+
+def test_flash_attn_roofline_takes_the_mean_call():
+    from bench import flops
+
+    r = _traced(calls=8.0, secs=0.01)
+    r.cell = dict(CELL, arch=_Windowed)
+    peak, bw = 197e12, 819e9
+    times = [max(ops / peak, nbytes / bw) for ops, nbytes in
+             _Windowed.attention_calls(None, None, None)]
+    got = run.read_metrics([("flash_attn_roofline", "%")], r)
+    assert got["flash_attn_roofline"]["value"] == \
+        pytest.approx(100.0 * sum(times) / 4 * 8.0 / 0.01, rel=1e-12)
+    # counted at full causal pairs, the windowed calls would read 2.29x
+    full = flops.flash_attention(1, 32, 4, 4096, 128)[0] / peak
+    assert full / times[0] == pytest.approx(2.2859, abs=1e-4)
+
+
+def test_flash_attn_roofline_is_silent_without_kernel_calls():
+    r = _traced()
+    r.trace["ops"] = {"fusion.3": (9.8, 1000.0)}
+    assert run.read_metrics([("flash_attn_roofline", "%")], r) == {}

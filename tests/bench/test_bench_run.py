@@ -64,6 +64,41 @@ def test_sound_run_gives_a_correct_result(trace):
     json.dumps(result)
 
 
+def test_a_new_architecture_needs_no_edit(tmp_path):
+    """A new architecture is a module, a configuration naming it and a
+    cell: a root holding a renamed copy of the dense module and only
+    new files beside the benchmark's own runs a tiny cell correct."""
+    spec = run.read_json(os.path.join(ROOT, "BENCHMARK.json"))
+    (cell,) = [w for w in spec["workloads"]
+               if w["name"] == "qwen2-1.5b.b4s1024"]
+    bench = tmp_path / "bench"
+    for sub in ("archs", "configs", "workloads"):
+        (bench / sub).mkdir(parents=True)
+    shutil.copytree(os.path.join(ROOT, "bench", "traffic"),
+                    bench / "traffic")
+    shutil.copy(os.path.join(ROOT, "bench", "archs", "dense.py"),
+                bench / "archs" / "copied_block.py")
+    model = run.read_json(os.path.join(ROOT, "bench", "configs",
+                                       "qwen2-1.5b.json"))
+    model.update(name="copied-model", arch="copied_block")
+    (bench / "configs" / "copied-model.json").write_text(json.dumps(model))
+    shutil.copy(os.path.join(ROOT, "bench", "workloads",
+                             "qwen2-1.5b.b4s1024.json"),
+                bench / "workloads" / "copied-model.b4s1024.json")
+    spec["configs"].append(dict(spec["configs"][0], name="copied-model",
+                                file="bench/configs/copied-model.json"))
+    spec["workloads"].append(dict(cell, name="copied-model.b4s1024",
+                                  config="copied-model"))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+
+    tiny = tiny_cell("copied-model.b4s1024", root=str(tmp_path))
+    assert tiny["arch"].__file__ == str(bench / "archs" / "copied_block.py")
+    result, _ = run.run(tiny, 2**31 + 78, 0.3, require_tpu=False,
+                        log=lambda *_: None)
+    assert result["correct"] is True
+    assert result["failed"] == 0 and result["attempted"] >= 1
+
+
 FAULTS = ["unchanged_state", "half_batch", "altered_token",
           "bfloat16_weights"]
 

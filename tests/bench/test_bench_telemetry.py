@@ -66,7 +66,7 @@ def _expected(sizes):
     weights = 4 * sizes["params"]
     h2d = weights + sizes["feeds"]
     return {"h2d_bytes": h2d, "d2h_bytes": weights + sizes["loss"],
-            "host_state_bytes": 6 * weights + h2d}
+            "host_state_bytes": weights + h2d}
 
 
 @pytest.mark.parametrize("name", ["h2d_bytes", "d2h_bytes",

@@ -1,5 +1,6 @@
 """A benchmark cell cut to a size the CPU tests can run."""
 
+import glob
 import os
 import sys
 
@@ -9,26 +10,32 @@ for p in (os.path.join(ROOT, "src"), ROOT):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-#: widths of the CPU-size twin of a configuration
-TINY = dict(hidden_size=64, intermediate_size=128, num_attention_heads=4,
-            head_dim=16, vocab_size=256, num_hidden_layers=2)
+#: every configuration in bench/configs, by name
+CONFIGS = sorted(os.path.basename(p)[:-len(".json")] for p in
+                 glob.glob(os.path.join(ROOT, "bench", "configs", "*.json")))
 
 
-def tiny_model(config: str) -> dict:
+def config_arch(config: str) -> tuple[dict, object]:
+    """Configuration ``config`` as run, and its architecture module."""
     from bench import run
 
     m = run.read_json(os.path.join(ROOT, "bench", "configs",
                                    config + ".json"))
-    kv = 2 if m["num_key_value_heads"] < m["num_attention_heads"] else 4
-    return dict(m, num_key_value_heads=kv, **TINY)
+    return m, run.load_arch(m["arch"])
 
 
-def tiny_cell(name: str = "qwen2-1.5b.b4s1024") -> dict:
-    """Cell ``name`` of BENCHMARK.json at tiny widths, batch 2 x 16,
-    with its own limits."""
+def tiny_model(config: str) -> dict:
+    """Configuration ``config`` at its architecture's CPU size."""
+    m, arch = config_arch(config)
+    return arch.tiny(m)
+
+
+def tiny_cell(name: str = "qwen2-1.5b.b4s1024", root: str = ROOT) -> dict:
+    """Cell ``name`` of BENCHMARK.json under ``root`` at tiny widths,
+    batch 2 x 16, with its own limits."""
     from bench import run
 
-    cell = run.load_cell(name)
-    cell["model"] = tiny_model(cell["config"])
+    cell = run.load_cell(name, root)
+    cell["model"] = cell["arch"].tiny(cell["model"])
     cell["traffic_mix"] = dict(cell["traffic_mix"], batch=2, seq=16)
     return cell
