@@ -15,7 +15,11 @@ The numbers compared:
 - ``change``: the same for the norm of the change after three steps,
   over the leaves whose reference gradient is at least a thousandth of
   the median leaf's (a key's bias gets no gradient under softmax, so
-  AdamW moves it by round-off alone).
+  AdamW moves it by round-off alone);
+- ``choice``, only where the architecture makes discrete choices (its
+  module's ``choices``, see ``bench/run.py``): the largest ``gap`` over
+  the steps between the program's choices, which the reference follows,
+  and the ones the reference's own arithmetic makes.
 """
 
 from __future__ import annotations
@@ -57,16 +61,29 @@ def numbers(prog: dict, ref: dict) -> dict[str, tuple[float, str]]:
               enumerate(zip(prog["loss"], ref["loss"]))]
     g_floor = float(np.median(list(ref["grad"].values())))
     moving = [n for n, g in ref["grad"].items() if g >= MOVING * g_floor]
-    return {
+    nums = {
         "loss": max(losses),
         "grad": _worst(prog["grad"], ref["grad"], ref["grad"]),
         "change": _worst(prog["change"], ref["change"], moving),
     }
+    if "gap" in ref:
+        nums["choice"] = max((g, f"step {i}")
+                             for i, g in enumerate(ref["gap"]))
+    return nums
 
 
 def verdict(nums: dict, limits: dict) -> tuple[bool, dict]:
     """Whether every number with a limit is within it, and
-    ``{name: {"value", "limit", "at"}}`` of the numbers compared."""
+    ``{name: {"value", "limit", "at"}}`` of the numbers compared.  A
+    cell may leave out a number that has no upper reading (PERF.md
+    names it with its readings); a limit without its number, or a
+    ``choice`` without a limit, is an error: a cell whose architecture
+    makes choices holds them to a limit."""
+    missing = sorted(set(limits) - set(nums)) + \
+        (["choice"] if "choice" in nums and "choice" not in limits else [])
+    if missing:
+        raise ValueError(f"numbers {sorted(nums)} against limits "
+                         f"{sorted(limits)}: {missing} unmatched")
     checks = {n: {"value": nums[n][0], "limit": lim, "at": nums[n][1]}
               for n, lim in limits.items()}
     ok = all(np.isfinite(c["value"]) and c["value"] <= c["limit"]

@@ -12,7 +12,8 @@ Readings: ``--seeds`` sound runs; ``--control`` runs of the control, the
 program at the next precision below the configuration's
 (``jax.default_matmul_precision("high")``, three bfloat16 passes, where
 the configuration states "highest"); and ``--faults`` runs of each fault
-in ``bench/faults.py``.  Every reading prints, and is appended to
+the cell can have (``bench/faults.py:of``: the general ones and the
+architecture's own).  Every reading prints, and is appended to
 ``--out``, as one JSON line ``{"kind", "seed", "correct", "numbers"}``
 with the numbers of ``bench/check.py``.  The benchmark's own runs never
 run this; it needs a TPU like them.
@@ -58,7 +59,7 @@ def readings(cell: dict, plan, out: str | None = None,
             trainers[key] = bench_run.Trainer(this, devs,
                                               bench_run.Spans(), quiet)
         trainer = trainers[key]
-        patch = faults.FAULTS.get(kind, contextlib.nullcontext)
+        patch = faults.of(cell["arch"]).get(kind, contextlib.nullcontext)
         with patch():
             prog = trainer.start(seed, quiet)
         trainer.release()
@@ -74,6 +75,23 @@ def readings(cell: dict, plan, out: str | None = None,
     return lines
 
 
+def plan_of(cell: dict, first_seed: int, seeds: int, control: int,
+            fault_runs: int) -> list[tuple[str, int]]:
+    """``(kind, seed)`` of each reading, seeds counting up from
+    ``first_seed``: the sound runs, each fault of the cell but the
+    unchanged state (it reads 1 by the measure of ``grad``, with no
+    run), then the control."""
+    counts = [("sound", seeds)] + \
+        [(name, fault_runs) for name in faults.of(cell["arch"])
+         if name != "unchanged_state"] + [("control", control)]
+    plan, seed = [], first_seed
+    for kind, count in counts:
+        for _ in range(count):
+            plan.append((kind, seed))
+            seed += 1
+    return plan
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -83,15 +101,9 @@ def main(argv=None) -> int:
     ap.add_argument("--faults", type=int, default=3)
     ap.add_argument("--out")
     args = ap.parse_args(argv)
-    counts = [("sound", args.seeds)] + \
-        [(name, args.faults) for name in faults.FAULTS
-         if name != "unchanged_state"] + [("control", args.control)]
-    plan, seed = [], args.first_seed
-    for kind, count in counts:
-        for _ in range(count):
-            plan.append((kind, seed))
-            seed += 1
-    readings(bench_run.load_cell(args.workload), plan, args.out)
+    cell = bench_run.load_cell(args.workload)
+    readings(cell, plan_of(cell, args.first_seed, args.seeds,
+                           args.control, args.faults), args.out)
     return 0
 
 

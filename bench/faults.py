@@ -1,7 +1,9 @@
 """Faults planted under the timed path, to show that the comparison
 fails them.  Each is a context manager that patches the program for as
 long as it is entered; ``bench/control.py`` and the CPU tests run a
-cell under each and expect ``correct`` to come out false.
+cell under each and expect ``correct`` to come out false.  Faults of
+one architecture's own mechanism live in its module's ``FAULTS``
+(``bench/archs``); :func:`of` gives a cell's faults, both together.
 """
 
 from __future__ import annotations
@@ -48,9 +50,11 @@ def _feeds_fault(change):
 
 def half_batch():
     """Each step leaves out the second half of its batch: the first
-    half stands in for it, so the mean is taken over the rest."""
+    half stands in for it, so the mean is taken over the rest.  A batch
+    of one row loses the second half of its positions instead."""
     def change(feeds):
-        return {k: np.concatenate([v[:len(v) // 2]] * 2)
+        axis = 0 if len(feeds["ids"]) > 1 else 1
+        return {k: np.concatenate([np.split(v, 2, axis)[0]] * 2, axis)
                 for k, v in feeds.items()}
     return _feeds_fault(change)
 
@@ -82,3 +86,14 @@ def bfloat16_weights():
 
 FAULTS = {"unchanged_state": unchanged_state, "half_batch": half_batch,
           "altered_token": altered_token}
+
+
+def of(arch) -> dict:
+    """The faults a cell of architecture ``arch`` can have: ``FAULTS``
+    and the module's own ``FAULTS``.  A name in both is an error."""
+    own = getattr(arch, "FAULTS", {})
+    both = sorted(set(FAULTS) & set(own))
+    if both:
+        raise ValueError(f"faults {both} are defined both in "
+                         f"bench/faults.py and in {arch.__file__}")
+    return {**FAULTS, **own}

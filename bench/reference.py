@@ -47,9 +47,17 @@ def weight_maker(arch, m: dict):
 def loss_and_grad(arch, m: dict):
     """Jitted ``(params, ids, labels) -> (loss, grads)`` of
     ``arch.loss``; the caller fixes the matmul precision around the
-    call."""
+    call.  For an architecture that makes discrete choices (its module
+    defines ``choices``): ``(params, ids, labels, choices) -> ((loss,
+    gap), grads)``, the loss following the program's ``choices`` of the
+    step and ``gap`` how far they are from the reference's own (see
+    ``bench/run.py``)."""
+    if not hasattr(arch, "choices"):
+        return jax.jit(jax.value_and_grad(
+            lambda p, ids, labels: arch.loss(p, ids, labels, m)))
     return jax.jit(jax.value_and_grad(
-        lambda p, ids, labels: arch.loss(p, ids, labels, m)))
+        lambda p, ids, labels, c: arch.loss(p, ids, labels, m, choices=c),
+        has_aux=True))
 
 
 def adamw(opt: dict):

@@ -15,7 +15,26 @@ a traffic mix or a metric as new files plus entries in
   program as the system builds it, the parameters in the program's
   order, the plain float32 loss, the training work per token, the
   flash-attention calls of a step and the CPU-size twin (see
-  ``bench/archs/dense.py``);
+  ``bench/archs/dense.py``), and two optional parts:
+
+  - ``choices(m) -> list[str]``: the program tensors whose values the
+    step decides by a discontinuous rule (an argmax, a top-k, a
+    threshold), such as an expert router's top-k ids.  Every step
+    fetches them, and the reference is handed each checked step's:
+    ``loss(params, ids, labels, m, choices=...)`` then follows them and
+    returns ``(loss, gap)``, ``gap >= 0`` being how far they are from
+    the choices its own arithmetic makes (for top-k, the most an
+    unpicked score exceeds a picked one, over the scores' scale).  A
+    rounding-level difference can flip such a choice, which moves a
+    gradient far more than the limits allow; following the program's
+    choices keeps ``loss``, ``grad`` and ``change`` to rounding, and the
+    number ``choice`` (``bench/check.py``) holds the choices themselves.
+    The window fetches them too, so that it runs the executable the
+    checked steps ran; the record keeps the first steps' only.
+  - ``FAULTS``: ``{name: context manager}`` planted under the timed
+    path, with the contract of ``bench/faults.py``, for faults of the
+    architecture's own mechanism; ``bench/control.py`` runs them with
+    the general ones.
 - ``bench/traffic/<traffic>.json``: the job (batch, sequence length,
   optimizer settings); ids and labels are drawn from ``(seed, step)``;
 - ``bench/workloads/<cell>.json``: the layout (``dp``, ``tp``) and the
@@ -373,15 +392,24 @@ def log_window_noise(t0, marks, ticks0, use0, gcw, log) -> None:
         f"{100 * steal / max(total, 1):.2f}%")
 
 
+def choice_names(arch, m: dict) -> list[str]:
+    """The program tensors of model ``m`` holding the step's discrete
+    choices: the architecture's ``choices(m)``, or none."""
+    return list(arch.choices(m)) if hasattr(arch, "choices") else []
+
+
 def reference_record(arch, m: dict, opt: dict, seed: int, batch: int,
                      seq: int, precision: str = "highest",
-                     log=lambda *_: None) -> dict:
+                     log=lambda *_: None, choices: list | None = None
+                     ) -> dict:
     """The reference's record of the first ``FIRST_STEPS`` steps of
     model ``m`` of architecture ``arch``, on the default device at
     ``precision``.  Weights and gradients stay on the device; AdamW runs
     there leaf by leaf, with the moments kept on the host between steps
     (at 14B widths they would not fit beside the gradients on one
-    chip)."""
+    chip).  For an architecture that makes discrete choices,
+    ``choices`` are the program's of each step: the loss follows them
+    and the record keeps each step's ``gap``."""
     import jax
     import jax.numpy as jnp
 
@@ -394,12 +422,19 @@ def reference_record(arch, m: dict, opt: dict, seed: int, batch: int,
     params = make(lo, hi)
     mom, vel = {}, {}
     rec: dict = {"loss": [], "grad": {}}
+    if choices is not None:
+        rec["gap"] = []
     times = []
     for t in range(FIRST_STEPS):
         t0 = time.perf_counter()
         f = feeds(seed, t, batch, seq, m["vocab_size"])
         with jax.default_matmul_precision(precision):
-            loss, grads = loss_and_grad(params, f["ids"], f["labels"])
+            if choices is None:
+                loss, grads = loss_and_grad(params, f["ids"], f["labels"])
+            else:
+                (loss, gap), grads = loss_and_grad(
+                    params, f["ids"], f["labels"], choices[t])
+                rec["gap"].append(float(gap))
         rec["loss"].append(float(loss))
         scale = clip(grads)
         for name in list(params):
@@ -491,8 +526,10 @@ class Trainer:
                     prog, 0, executor=ex,
                     optimizer=AdamWConfig(**self.tr["optimizer"]))
                 tplan = prog.compile_train(0)
+            self.fetches = choice_names(cell["arch"], self.m)
             with spans.span("compile"):
-                self.lw = ex.lowered(tplan, tplan.train_fetches)
+                self.lw = ex.lowered(tplan,
+                                     tplan.train_fetches + self.fetches)
                 compiled = self.lw.lower(leaf_dtypes(self.lw)).compile()
         mem = compiled.memory_analysis()
         st = self.lw.stats
@@ -504,22 +541,27 @@ class Trainer:
             f"segments {st.compute_segments}, grouped reduces "
             f"{st.grouped_reduces}")
 
-    def step(self, seed: int, i: int) -> float:
-        """Train step ``i`` of ``seed``'s traffic; returns its loss."""
+    def step(self, seed: int, i: int) -> tuple[float, dict]:
+        """Train step ``i`` of ``seed``'s traffic; returns its loss and
+        the architecture's choices (``choice_names``), fetched on every
+        step so that every step runs the one compiled program.  The
+        step's result, which holds its gradients on the device, is
+        dropped here."""
         import jax
 
         tr = self.tr
         with jax.default_matmul_precision(self.precision), \
                 self.spans.span("step"):
-            out = self.sess.train_step(feeds(seed, i, tr["batch"],
-                                             tr["seq"],
-                                             self.m["vocab_size"]))
-        return out.loss
+            out = self.sess.train_step(
+                feeds(seed, i, tr["batch"], tr["seq"],
+                      self.m["vocab_size"]), fetches=self.fetches)
+        return out.loss, out.outputs
 
     def start(self, seed: int, log=print) -> dict:
         """Load ``seed``'s weights and run the first steps; returns the
         program's record of them (see ``bench/check.py``)."""
         import jax
+        from repro.core.simulator import gather
 
         from bench import reference
 
@@ -530,10 +572,16 @@ class Trainer:
         del weights
         self.sess.opt_state = None
         rec: dict = {"loss": []}
+        if self.fetches:
+            rec["choices"] = []
         with CompileWatch() as warm:
             for i in range(FIRST_STEPS):
                 t0 = time.perf_counter()
-                rec["loss"].append(self.step(seed, i))
+                loss, fetched = self.step(seed, i)
+                rec["loss"].append(loss)
+                if self.fetches:
+                    rec["choices"].append({n: gather(fetched[n])
+                                           for n in self.fetches})
                 if i == 0:
                     self.spans.totals["first_step"] = \
                         time.perf_counter() - t0
@@ -558,7 +606,8 @@ def compare(cell: dict, seed: int, prog: dict, log=print):
     m, tr = cell["model"], cell["traffic_mix"]
     t0 = time.perf_counter()
     ref = reference_record(cell["arch"], m, tr["optimizer"], seed,
-                           tr["batch"], tr["seq"], log=log)
+                           tr["batch"], tr["seq"], log=log,
+                           choices=prog.get("choices"))
     log(f"reference: {time.perf_counter() - t0:.1f} s; host {host_memory()}")
     nums = check.numbers(prog, ref)
     ok, _ = check.verdict(nums, cell["limits"])
@@ -595,8 +644,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool = False, *,
                 t0 = time.perf_counter()
                 r.setup_s = t0 - T_START
                 while True:
-                    losses.append(trainer.step(seed,
-                                               FIRST_STEPS + len(losses)))
+                    losses.append(trainer.step(
+                        seed, FIRST_STEPS + len(losses))[0])
                     t1 = time.perf_counter()
                     marks.append((t1, dict(spans.totals)))
                     if t1 - t0 >= seconds:

@@ -69,15 +69,19 @@ def test_loss_and_grads_match_graph_block_reference(config):
 @pytest.mark.parametrize("config", CONFIGS)
 def test_loss_and_grads_match_the_program(config):
     """The program's own train step, built by the architecture module
-    and run as the benchmark runs it, against the reference."""
+    and run as the benchmark runs it, against the reference; where the
+    architecture makes discrete choices, the step fetches them and the
+    reference follows them, as in a run."""
     from jax.sharding import Mesh
     from repro import api
+    from repro.core.simulator import gather
     from repro.optim.adamw import AdamWConfig
 
     m, arch = tiny_model(config), _arch(config)
     params = reference.weight_maker(arch, m)(
         *reference.seed_words(2**31 + 6))
     ids, labels = _batch(m, seed=4)
+    names = run.choice_names(arch, m)
     with jax.default_matmul_precision("highest"):
         sess = api.Session(
             arch.program(m, 2, 16, LAYOUT), 0,
@@ -86,8 +90,18 @@ def test_loss_and_grads_match_the_program(config):
             optimizer=AdamWConfig())
         sess.load(jax.device_get(params))
         out = sess.train_step({"ids": np.asarray(ids),
-                               "labels": np.asarray(labels)})
-        loss, grads = reference.loss_and_grad(arch, m)(params, ids, labels)
+                               "labels": np.asarray(labels)},
+                              fetches=names)
+        if names:
+            (loss, gap), grads = reference.loss_and_grad(arch, m)(
+                params, ids, labels,
+                {n: gather(out.outputs[n]) for n in names})
+            # the choices the program made are the reference's own, to
+            # the tolerance the loss is held to
+            assert 0.0 <= float(gap) <= 1e-6
+        else:
+            loss, grads = reference.loss_and_grad(arch, m)(params, ids,
+                                                           labels)
     assert set(out.grads) == set(params)
     assert out.loss == pytest.approx(float(loss), rel=1e-6)
     _close({n: out.grad_value(n) for n in params},

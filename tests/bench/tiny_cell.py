@@ -31,11 +31,15 @@ def tiny_model(config: str) -> dict:
 
 
 def tiny_cell(name: str = "qwen2-1.5b.b4s1024", root: str = ROOT) -> dict:
-    """Cell ``name`` of BENCHMARK.json under ``root`` at tiny widths,
-    batch 2 x 16, with its own limits."""
+    """Cell ``name`` of BENCHMARK.json under ``root`` at its
+    architecture's tiny widths (``tiny``), with half its rows (one at
+    least) of a 64th of its length (B4 x S1024 -> 2 x 16, B1 x S4096 ->
+    1 x 64), with its own limits."""
     from bench import run
 
     cell = run.load_cell(name, root)
     cell["model"] = cell["arch"].tiny(cell["model"])
-    cell["traffic_mix"] = dict(cell["traffic_mix"], batch=2, seq=16)
+    tr = cell["traffic_mix"]
+    cell["traffic_mix"] = dict(tr, batch=max(1, tr["batch"] // 2),
+                               seq=tr["seq"] // 64)
     return cell
